@@ -142,20 +142,16 @@ def _run_subprocess() -> dict:
             timeout=560,
         )
     if out.returncode != 0:
-        return {"error": out.stderr[-500:]}
+        raise RuntimeError(f"cost-model bench subprocess failed: {out.stderr[-2000:]}")
     for line in out.stdout.splitlines():
         if line.startswith("COSTMODEL_JSON "):
             return json.loads(line[len("COSTMODEL_JSON "):])
-    return {"error": "no COSTMODEL_JSON line in subprocess output"}
+    raise RuntimeError("no COSTMODEL_JSON line in the cost-model bench subprocess output")
 
 
 def run(as_dict: bool = False):
     print("# Cost model predicted vs measured (8 virtual CPU devices, 512^3 GEMM)")
     doc = _run_subprocess()
-    if "error" in doc:
-        # don't fail the whole bench suite on subprocess quirks
-        print(f"subprocess failed: {doc['error']}")
-        return doc if as_dict else True
     print("schedule,predicted_ms,measured_ms,ratio")
     for r in doc["rows"]:
         print(f"{r['schedule']},{r['predicted_ms']},{r['measured_ms']},{r['ratio']}")

@@ -8,6 +8,7 @@ import subprocess
 import sys
 import textwrap
 
+from repro.launch.mesh import forced_device_env
 from repro.parallel.pipeline import bubble_fraction
 from repro.parallel.systolic import phase_counts
 
@@ -57,17 +58,14 @@ def run(csv=False):
         print("MATCH")
         """
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = "src"
     out = subprocess.run(
-        [sys.executable, "-c", prog], capture_output=True, text=True, env=env,
+        [sys.executable, "-c", prog], capture_output=True, text=True,
+        env=forced_device_env(4),
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))), timeout=560,
     )
-    if out.returncode == 0:
-        print(out.stdout.strip())
-    else:  # don't fail the whole bench suite on subprocess quirks
-        print(f"subprocess failed: {out.stderr[-500:]}")
+    if out.returncode != 0:
+        raise RuntimeError(f"ring-collective subprocess failed: {out.stderr[-2000:]}")
+    print(out.stdout.strip())
     return True
 
 

@@ -135,11 +135,11 @@ def _run_subprocess(schedule: str = None) -> dict:
         timeout=560,
     )
     if out.returncode != 0:
-        return {"error": out.stderr[-500:]}
+        raise RuntimeError(f"sharded bench subprocess failed: {out.stderr[-2000:]}")
     for line in out.stdout.splitlines():
         if line.startswith("SHARDED_JSON "):
             return json.loads(line[len("SHARDED_JSON "):])
-    return {"error": "no SHARDED_JSON line in subprocess output"}
+    raise RuntimeError("no SHARDED_JSON line in the sharded bench subprocess output")
 
 
 def run(as_dict: bool = False, schedule: str = None):
@@ -149,13 +149,6 @@ def run(as_dict: bool = False, schedule: str = None):
         f"(8 virtual CPU devices, 512^3 GEMM{scope})"
     )
     doc = _run_subprocess(schedule)
-    if "error" in doc:
-        if schedule:
-            # the targeted smoke (CI) must FAIL loudly, not shrug
-            raise RuntimeError(f"sharded bench subprocess failed: {doc['error']}")
-        # don't fail the whole bench suite on subprocess quirks
-        print(f"subprocess failed: {doc['error']}")
-        return doc if as_dict else True
     print("case,schedule,bytes_moved,phases,ms_per_step,overlap_efficiency")
     for r in doc["rows"]:
         eff = r.get("overlap_efficiency")

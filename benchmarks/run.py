@@ -38,6 +38,7 @@ from benchmarks import (
     bench_stepcounts,
     bench_symmetric,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def bench_train():
@@ -107,6 +108,7 @@ def main() -> None:
     )
     ap.add_argument("--json-path", default="BENCH_kernels.json")
     args = ap.parse_args()
+    enable_compile_cache()
     names = [args.only] if args.only else list(SECTIONS)
     if args.json and "kernels" not in names:
         names.append("kernels")

@@ -384,7 +384,9 @@ def calibrate(
     records = run_probes(shapes, backend=backend)
     cache.add_records(platform, records)
     all_records = cache.records(platform)
-    coeffs = fit_coefficients(all_records, platform=platform)
+    coeffs = fit_coefficients(
+        all_records, platform=platform, init=_platform_defaults(platform)
+    )
     cache.set_coefficients(coeffs)
     if persist:
         cache.save()
@@ -409,7 +411,11 @@ def ingest(
     with _obs.span("calibrate.ingest", n=len(records), platform=platform) as sp:
         added = cache.add_records(platform, records)
         if added and refit:
-            coeffs = fit_coefficients(cache.records(platform), platform=platform)
+            coeffs = fit_coefficients(
+                cache.records(platform),
+                platform=platform,
+                init=_platform_defaults(platform),
+            )
             cache.set_coefficients(coeffs)
             clear_coefficients_memo()
         # `added == 0` means nothing changed (all records invalid or empty
@@ -427,6 +433,15 @@ def ingest(
 _COEFFS_MEMO: Dict[Tuple[str, str], CostCoefficients] = {}
 
 
+def _platform_defaults(platform: str) -> CostCoefficients:
+    """Shipped coefficients for the live devices of `platform` (TPU peaks
+    are keyed by the chip's device_kind)."""
+    import jax
+
+    kind = jax.devices(platform)[0].device_kind if platform == "tpu" else None
+    return default_coefficients(platform, kind)
+
+
 def current_coefficients(platform: Optional[str] = None) -> CostCoefficients:
     """Coefficients the planner should use NOW: the calibration file's fit
     for this platform when present, shipped defaults otherwise.  Memoized
@@ -440,15 +455,16 @@ def current_coefficients(platform: Optional[str] = None) -> CostCoefficients:
     memo_key = (platform, str(cache.path))
     got = _COEFFS_MEMO.get(memo_key)
     if got is None:
+        defaults = _platform_defaults(platform)
         try:
-            got = cache.coefficients(platform) or default_coefficients(platform)
+            got = cache.coefficients(platform) or defaults
         except Exception as e:  # pragma: no cover — load already degrades
             _rledger.record(
                 "costmodel.coefficients",
                 cause=f"{type(e).__name__}: {e}",
                 fallback="defaults",
             )
-            got = default_coefficients(platform)
+            got = defaults
         _COEFFS_MEMO[memo_key] = got
     return got
 

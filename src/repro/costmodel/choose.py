@@ -256,7 +256,15 @@ def _timed_tiebreak(
     import jax.numpy as jnp
 
     from repro.kernels import api
-    from repro.kernels.autotune import measure_best_ms
+    from repro.kernels.autotune import measure_best_ms, outside_trace
+
+    def measure(p) -> float:
+        a = jnp.ones(spec.batch + (spec.m, spec.k), spec.dtype_a)
+        b_shape = (
+            spec.batch + (spec.k, spec.n) if spec.batched_b else (spec.k, spec.n)
+        )
+        b = jnp.ones(b_shape, spec.dtype_b)
+        return measure_best_ms(p, a, b)
 
     for cand in legal[:2]:
         shard = shards.get(cand["name"])
@@ -264,12 +272,8 @@ def _timed_tiebreak(
             continue
         try:
             p = api.plan(dataclasses.replace(spec, shard=shard), mesh=mesh)
-            a = jnp.ones(spec.batch + (spec.m, spec.k), spec.dtype_a)
-            b_shape = (
-                spec.batch + (spec.k, spec.n) if spec.batched_b else (spec.k, spec.n)
-            )
-            b = jnp.ones(b_shape, spec.dtype_b)
-            cand["measured_ms"] = measure_best_ms(p, a, b)
+            # concrete operands even when planning inside a traced step
+            cand["measured_ms"] = outside_trace(measure, p)
         except Exception as e:
             _rledger.record(
                 "costmodel.tiebreak",

@@ -44,12 +44,14 @@ import numpy as np
 __all__ = [
     "COST_MODEL_VERSION",
     "CostCoefficients",
+    "TPU_PEAKS",
     "default_coefficients",
     "predict",
     "predict_blocks_ms",
     "repeat_amortization",
     "structure_step_factor",
     "terms_from_describe",
+    "tpu_peaks",
 ]
 
 COST_MODEL_VERSION = 1
@@ -106,19 +108,44 @@ class CostCoefficients:
         return cls(**kw)
 
 
-def default_coefficients(platform: Optional[str] = None) -> CostCoefficients:
-    """Shipped coefficients: TPU v5e roofline constants on TPU; CPU numbers
-    anchored to the measured `BENCH_kernels.json["xla_gemm"]` series
-    (~105–136 GFLOP/s f32 on the CI host).  Latency coefficients default to
-    zero — byte terms alone reproduce the legacy auto-schedule heuristic
-    exactly, and calibration fits the real fixed costs when asked."""
+# Published per-chip peaks, keyed by `jax.Device.device_kind`:
+# (bf16 FLOP/s, HBM bytes/s, ICI bytes/s per link).  Source: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of chip-to-chip interconnect (four links of 50 GB/s).
+TPU_PEAKS: Dict[str, Tuple[float, float, float]] = {
+    "TPU v5 lite": (197e12, 819e9, 50e9),
+}
+
+
+def tpu_peaks(device_kind: Optional[str]) -> Tuple[float, float, float]:
+    """The `TPU_PEAKS` row for a chip; a kind not in the table is an error,
+    never a silent v5e default."""
+    try:
+        return TPU_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU device_kind {device_kind!r};"
+            f" known: {sorted(TPU_PEAKS)}"
+        ) from None
+
+
+def default_coefficients(
+    platform: Optional[str] = None, device_kind: Optional[str] = None
+) -> CostCoefficients:
+    """Shipped coefficients: the chip's published peaks on TPU (`TPU_PEAKS`,
+    keyed by `device_kind`); CPU numbers anchored to the measured
+    `BENCH_kernels.json["xla_gemm"]` series (~105–136 GFLOP/s f32 on the CI
+    host).  Latency coefficients default to zero — byte terms alone
+    reproduce the legacy auto-schedule heuristic exactly, and calibration
+    fits the real fixed costs when asked."""
     if platform is None:
         platform = "cpu"
     if platform == "tpu":
+        flops, hbm, link = tpu_peaks(device_kind)
         return CostCoefficients(
-            flops_per_s=197e12,
-            hbm_bytes_per_s=819e9,
-            link_bytes_per_s=50e9,
+            flops_per_s=flops,
+            hbm_bytes_per_s=hbm,
+            link_bytes_per_s=link,
             backend_efficiency=(("pallas_mesh", 1.0), ("ref", 0.02), ("xla", 0.95)),
             platform="tpu",
         )

@@ -25,6 +25,7 @@ elsewhere.  A cache hit never searches.
 
 from __future__ import annotations
 
+import concurrent.futures
 import json
 import os
 import tempfile
@@ -37,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import metrics as _metrics
 from repro.obs import trace as _obs
 from repro.resilience import faults as _faults
 from repro.resilience import ledger as _rledger
@@ -51,6 +53,7 @@ __all__ = [
     "default_cache",
     "measure_best_ms",
     "model_score",
+    "outside_trace",
     "resolve_blocks",
     "vmem_bytes",
 ]
@@ -60,8 +63,9 @@ DEFAULT_CACHE_FILENAME = ".autotune_cache.json"
 _ENV_CACHE = "REPRO_AUTOTUNE_CACHE"
 
 _LANE = 128  # MXU tile edge — every candidate dimension is a multiple
-# Per-core VMEM is ~16 MiB; leave headroom for pipeline double-buffering
-# (Pallas keeps two in-flight copies of each input block).
+# A kernel's default scoped VMEM on v5e is 16 MiB; `vmem_bytes` already
+# counts the pipeline's double buffers, so the margin left here is for
+# Mosaic's own internal scratch.
 DEFAULT_VMEM_BUDGET = 12 * 1024 * 1024
 
 Blocks = Tuple[int, int, int]
@@ -91,14 +95,19 @@ def vmem_bytes(
     has_bias: bool = False,
     has_residual: bool = False,
 ) -> int:
-    """Per-grid-cell VMEM working set: A-tile + B-tile + f32 acc (+ epilogue)."""
+    """Per-grid-cell VMEM working set of the mesh kernel.
+
+    Pallas double-buffers every blocked operand and the output (the next
+    block's DMA overlaps this block's compute), so the A, B and output
+    tiles — and the bias row / residual tile when fused — count twice; the
+    f32 accumulator is one scratch buffer."""
     ds = jnp.dtype(dtype).itemsize
-    total = (bm * bk + bk * bn) * ds + bm * bn * 4
+    tiles = (bm * bk + bk * bn + bm * bn) * ds
     if has_bias:
-        total += bn * 4
+        tiles += 8 * bn * 4  # a (1, bn) f32 row pads to 8 sublanes
     if has_residual:
-        total += bm * bn * ds
-    return total
+        tiles += bm * bn * ds
+    return 2 * tiles + bm * bn * 4
 
 
 def _dim_candidates(dim: int, aligns: Tuple[int, ...]) -> List[int]:
@@ -397,6 +406,16 @@ def _warm_start(
     return best
 
 
+def outside_trace(fn: Callable, *args):
+    """`fn(*args)` on a helper thread.  Plans are built while a model's step
+    is being traced, and JAX's trace state belongs to the thread: there the
+    call runs eagerly on concrete arrays, where inside the trace it would
+    stage tracers (or, under `ensure_compile_time_eval`, fail to evaluate
+    an interpret-mode kernel)."""
+    with concurrent.futures.ThreadPoolExecutor(1) as ex:
+        return ex.submit(fn, *args).result()
+
+
 def measure_best_ms(fn: Callable, *args, warmup: int = 1, reps: int = 3) -> float:
     """Best-of-`reps` wall time of `fn(*args)` in milliseconds, compile
     excluded (`warmup` untimed calls first).  Results are blocked on when
@@ -522,13 +541,14 @@ def autotune(
         measure = measure or _default_measure
         timed: List[Tuple[float, Blocks]] = []
         failed = 0
+        t_search = time.perf_counter()
         for blk in cands[:max_timed]:
             # A candidate that fails to compile/run is skipped, not fatal —
             # the search degrades toward the analytic model instead of
             # crashing plan construction.
             try:
                 with _obs.span("autotune.measure", key=key, blocks=list(blk)):
-                    cand_ms = measure(m, k, n, dtype, backend, blk)
+                    cand_ms = outside_trace(measure, m, k, n, dtype, backend, blk)
                 timed.append((cand_ms, blk))
             except Exception as e:
                 failed += 1
@@ -538,6 +558,12 @@ def autotune(
                     fallback="skip-candidate",
                     blocks=blk,
                 )
+        # A counter, not the spans above: searches run while a step is being
+        # traced, where spans are suppressed.
+        _metrics.counter(
+            "autotune_timed_seconds_total",
+            "wall time of timed block searches, candidate compiles included",
+        ).inc(time.perf_counter() - t_search)
         if timed:
             ms, best = min(timed, key=lambda t: t[0])
             source = "timed"

@@ -28,9 +28,13 @@ door resolves to `xla_gather`; on TPU it resolves to `pallas_paged`.
 Layout contract (single decode token per sequence slot):
 
   q             (S, H, hd)           one query token per slot
-  k_pool/v_pool (P, page_size, KV, hd)  shared pools; page 0 is the
+  k_pool/v_pool (P, KV, page_size, hd)  shared pools; page 0 is the
                                      scheduler's scratch page (inactive
-                                     slots write there, never read back)
+                                     slots write there, never read back).
+                                     KV-head-major inside a page, so one
+                                     (page, kv-head) block is a full
+                                     (page_size, hd) tile and obeys the
+                                     TPU's (8, 128) block rule
   block_tables  (S, n_pages) int32   page ids per slot; unallocated -> 0
   lengths       (S,) int32           valid context length INCLUDING the
                                      freshly written token (= pos + 1)
@@ -74,10 +78,11 @@ _NEG_INF = -1e30
 
 
 def gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """(P, ps, KV, hd) pool + (S, n) tables -> (S, n*ps, KV, hd) context."""
+    """(P, KV, ps, hd) pool + (S, n) tables -> (S, n*ps, KV, hd) context."""
     s, n = block_tables.shape
-    _, ps, kvh, hd = pool.shape
-    return jnp.take(pool, block_tables, axis=0).reshape(s, n * ps, kvh, hd)
+    _, kvh, ps, hd = pool.shape
+    pages = jnp.take(pool, block_tables, axis=0)  # (S, n, KV, ps, hd)
+    return pages.transpose(0, 1, 3, 2, 4).reshape(s, n * ps, kvh, hd)
 
 
 def paged_attention_xla(
@@ -123,11 +128,11 @@ def _paged_kernel(
     bt_ref,  # SMEM (S, n_pages) block tables (scalar prefetch)
     len_ref,  # SMEM (S,) valid lengths (scalar prefetch)
     q_ref,  # (rep, hd) query rows for this (slot, kv-head)
-    k_ref,  # (ps, hd) one page of keys
-    v_ref,  # (ps, hd) one page of values
+    k_ref,  # (ps, hd) one page of keys for this kv-head
+    v_ref,  # (ps, hd) one page of values for this kv-head
     o_ref,  # (rep, hd)
-    m_ref,  # VMEM (rep,) running max
-    l_ref,  # VMEM (rep,) running denominator
+    m_ref,  # VMEM (rep, 1) running max
+    l_ref,  # VMEM (rep, 1) running denominator
     acc_ref,  # VMEM (rep, hd) f32 accumulator
     *,
     page_size: int,
@@ -151,31 +156,28 @@ def _paged_kernel(
     # analogue of the grouped kernel's ragged steering).
     @pl.when(start < length)
     def _accumulate():
-        sc = (
-            jnp.dot(q_ref[...], k_ref[...].T, preferred_element_type=jnp.float32)
-            * scale
-        )  # (rep, ps)
+        q = q_ref[...].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
+        sc = jnp.einsum("gd,td->gt", q, k, preferred_element_type=jnp.float32)
+        sc = sc * scale  # (rep, ps)
         kpos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         sc = jnp.where(kpos < length, sc, _NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
-        prob = jnp.exp(sc - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        prob = jnp.exp(sc - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(prob, axis=-1)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(prob, axis=-1, keepdims=True)
         m_ref[...] = m_new
-        acc_ref[...] += (
-            jnp.dot(
-                prob.astype(v_ref.dtype), v_ref[...],
-                preferred_element_type=jnp.float32,
-            )
-            - (1.0 - corr[:, None]) * acc_ref[...]
+        pv = jnp.dot(
+            prob.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32
         )
+        acc_ref[...] = acc_ref[...] * corr + pv
 
     @pl.when(p == n_pages - 1)
     def _flush():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)  # length >= 1 in practice
-        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -197,7 +199,7 @@ def paged_attention_pallas(
             " (scalar-prefetch grid specs); use the xla_gather impl"
         )
     s, h, hd = q.shape
-    n_pool, ps, kvh, hd2 = k_pool.shape
+    n_pool, kvh, ps, hd2 = k_pool.shape
     if hd != hd2:
         raise ValueError(f"head_dim mismatch: q {q.shape} vs pool {k_pool.shape}")
     if v_pool.shape != k_pool.shape:
@@ -222,18 +224,18 @@ def paged_attention_pallas(
         in_specs=[
             pl.BlockSpec((None, None, rep, hd), lambda i, j, p, bt, ln: (i, j, 0, 0)),
             pl.BlockSpec(
-                (None, ps, None, hd), lambda i, j, p, bt, ln: (bt[i, p], 0, j, 0)
+                (None, None, ps, hd), lambda i, j, p, bt, ln: (bt[i, p], j, 0, 0)
             ),
             pl.BlockSpec(
-                (None, ps, None, hd), lambda i, j, p, bt, ln: (bt[i, p], 0, j, 0)
+                (None, None, ps, hd), lambda i, j, p, bt, ln: (bt[i, p], j, 0, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
             (None, None, rep, hd), lambda i, j, p, bt, ln: (i, j, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((rep,), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, hd), jnp.float32),
         ],
     )

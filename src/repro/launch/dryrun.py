@@ -201,16 +201,8 @@ def _full_depth_units(cfg) -> float:
     return float(cfg.num_layers)
 
 
-def _normalize_cost(cost):
-    """compiled.cost_analysis() returns a per-device LIST of dicts on jax
-    0.4.x and a flat dict on newer releases — normalize to the dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def _cost_triple(compiled) -> Dict[str, float]:
-    cost = _normalize_cost(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     coll = collective_stats(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -338,7 +330,7 @@ def run_cell(
     t_compile = time.monotonic() - t0
 
     mem = compiled.memory_analysis()
-    cost = _normalize_cost(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_stats(hlo)
 

@@ -11,11 +11,7 @@ import math
 import os
 
 import jax
-
-try:  # jax >= 0.5 exposes AxisType; 0.4.x builds (e.g. 0.4.37) do not.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover — version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "forced_device_env", "PROD_TP"]
 
@@ -23,13 +19,9 @@ PROD_TP = 16  # 'model' axis size on the production meshes
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with axis_types when the installed jax supports it."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axes: sharding constraints inside jit (the
+    model's `ShardCtx.c`) are refused on the Explicit axes JAX defaults to."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -49,11 +41,14 @@ def forced_device_env(n_devices: int, *, pythonpath=("src",)) -> dict:
     devices (multi-device tests/benches re-exec because the parent process
     already initialized jax at its own device count).
 
+    Pins the child to the CPU backend: on a TPU host the parent may already
+    hold the chip, and a child that reaches for it fails or hangs.
     Replaces any existing --xla_force_host_platform_device_count in XLA_FLAGS
     (appending would leave duplicate flags with parser-order semantics) and
     prepends `pythonpath` entries while keeping the inherited PYTHONPATH.
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = [
         f
         for f in env.get("XLA_FLAGS", "").split()

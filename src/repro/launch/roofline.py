@@ -31,6 +31,8 @@ import json
 import os
 from typing import Any, Dict, List, Optional
 
+from repro.costmodel.model import TPU_PEAKS
+
 __all__ = [
     "PEAK_FLOPS",
     "HBM_BW",
@@ -41,9 +43,9 @@ __all__ = [
     "render_markdown",
 ]
 
-PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
-HBM_BW = 819e9       # bytes/s per chip
-LINK_BW = 50e9       # bytes/s per ICI link
+# The dry-run pod is v5e; its per-chip peaks come from the one device_kind
+# table the cost model keeps.
+PEAK_FLOPS, HBM_BW, LINK_BW = TPU_PEAKS["TPU v5 lite"]
 
 _HINTS = {
     "compute": "reduce recompute (remat policy) / pick a lower-waste schedule — HLO FLOPs exceed the useful-model floor",

@@ -285,11 +285,10 @@ class ContinuousBatchingServer:
                 def put(pool, c):
                     layers, _, t, kvh, hd = c.shape
                     n = pages.shape[0]
-                    ps = pool.shape[2]
+                    ps = pool.shape[3]
                     c2 = jnp.pad(c[:, 0], [(0, 0), (0, n * ps - t), (0, 0), (0, 0)])
-                    return pool.at[:, pages].set(
-                        c2.reshape(layers, n, ps, kvh, hd).astype(pool.dtype)
-                    )
+                    c2 = c2.reshape(layers, n, ps, kvh, hd).transpose(0, 1, 3, 2, 4)
+                    return pool.at[:, pages].set(c2.astype(pool.dtype))
 
                 return {
                     "k": put(pools["k"], caches["k"]),
@@ -567,25 +566,36 @@ class ContinuousBatchingServer:
         self._evict(victim, "preempted", f"pages_exhausted(for={seq.req.rid})")
         return victim is not seq
 
-    def _decode_tick(self) -> None:
+    def decode_inputs(self):
+        """Host arrays for the next decode tick over the non-stalled active
+        sequences: (ready, tokens (S, 1), positions (S,), block tables
+        (S, max_pages_per_seq) or None when the family has no pages)."""
         ready = [s for s in self._active if not s.stalled]
-        if not ready:
-            return
         s_max = self.cfg.max_slots
         tokens = np.zeros((s_max, 1), np.int32)
         positions = np.zeros((s_max,), np.int32)
+        tables = (
+            np.zeros((s_max, self.cfg.max_pages_per_seq), np.int32)
+            if self._paged
+            else None
+        )
         for seq in ready:
             tokens[seq.slot, 0] = seq.tokens[-1]
             positions[seq.slot] = seq.pos
+            if self._paged:
+                tables[seq.slot, : len(seq.pages)] = seq.pages
+        return ready, tokens, positions, tables
+
+    def _decode_tick(self) -> None:
+        ready, tokens, positions, tables = self.decode_inputs()
+        if not ready:
+            return
         # The decode span covers the jitted step AND the host sync
         # (np.asarray blocks), so its duration is the honest per-tick
         # decode wall time — the same number the tpot histogram records.
         t0 = time.monotonic()
         with _obs.span("serve.decode", slots=len(ready), tick=self._tick):
             if self._paged:
-                tables = np.zeros((s_max, self.cfg.max_pages_per_seq), np.int32)
-                for seq in ready:
-                    tables[seq.slot, : len(seq.pages)] = seq.pages
                 nxt, self.pools = self._decode(
                     self.params,
                     jnp.asarray(tokens),
@@ -631,9 +641,12 @@ class ContinuousBatchingServer:
         except Exception:
             pass  # planner degrades to defaults on its own
 
+        # The canary runs on the backend the model's GEMMs use, so a server
+        # whose projections ride the mesh kernel plans nothing else.
         a = jnp.ones((8, 8), jnp.float32)
         canary = api.plan(
             api.GemmSpec.from_operands(a, a, blocks=(8, 8, 8)),
+            backend="pallas_mesh" if self.model.cfg.use_mesh_kernel else None,
             guard_nonfinite="zero_and_record",
         )
         # Async dispatch (DESIGN.md §15): the cold compile proceeds in the

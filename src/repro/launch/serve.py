@@ -21,8 +21,9 @@ Robustness (DESIGN.md §11): `--requests N` serves N independent prompt
 batches through `serve_requests`, which isolates each request — one request
 raising (poisoned input, injected fault at the `serve.request` site) is
 reported, recorded in the resilience ledger, and *skipped*; the remaining
-requests still serve.  Any degradation events accumulated during the run
-(backend fallbacks, guard trips, retries) are printed at exit.
+requests still serve, and the process then exits non-zero.  Any degradation
+events accumulated during the run (backend fallbacks, guard trips, retries)
+are printed at exit.
 """
 
 from __future__ import annotations
@@ -37,6 +38,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.kernels import api as kernel_api
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ShardCtx, get_model
 from repro.obs import trace as _obs
 from repro.resilience import faults as _faults
@@ -330,6 +332,7 @@ def main(argv=None) -> None:
         " 2x4 (needs that many devices; sharding constraints activate)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.obs_export:
         # Tracing + both bridge feeds go live BEFORE any model work so the
@@ -367,6 +370,7 @@ def main(argv=None) -> None:
     ]
 
     _faults.install_env_plan()
+    skipped = 0
     if args.scheduler:
         from repro.launch.scheduler import ContinuousBatchingServer, Request, ServeConfig
 
@@ -407,6 +411,7 @@ def main(argv=None) -> None:
         print(f"[serve] {server.counters}, {rate:.1f} tok/s")
     else:
         results = serve_requests(model, params, request_prompts, gen_len=args.gen, ctx=ctx)
+        skipped = sum(r is None for r in results)
         print(f"[serve] {args.arch} batch={args.batch} prompt={args.prompt_len} gen={args.gen}")
         for r, res in enumerate(results):
             if res is None:
@@ -448,6 +453,8 @@ def main(argv=None) -> None:
             f"[serve] obs export: {args.obs_export} (+.prom, +.jsonl), "
             f"{ingested} calibration records ingested"
         )
+    if skipped:
+        raise SystemExit(f"[serve] {skipped} of {len(request_prompts)} requests failed")
 
 
 if __name__ == "__main__":

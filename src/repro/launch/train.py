@@ -29,6 +29,7 @@ from repro.configs import SHAPES, get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.checkpoint.manager import CheckpointManager
 from repro.checkpoint.async_writer import AsyncCheckpointer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import ShardCtx, get_model
 from repro.optim import AdamWConfig, warmup_cosine
@@ -102,6 +103,7 @@ def main(argv=None) -> None:
     ap.add_argument("--step-deadline-s", type=float, default=None)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -153,7 +153,7 @@ def attention_paged_decode(
     cfg,
     ctx: ShardCtx,
     *,
-    k_pool: jax.Array,  # (P, page_size, KV, hd) shared page pool
+    k_pool: jax.Array,  # (P, KV, page_size, hd) shared page pool
     v_pool: jax.Array,
     block_tables: jax.Array,  # (S, n_pages) int32
     positions: jax.Array,  # (S,) int32 — each slot's current length
@@ -183,29 +183,20 @@ def attention_paged_decode(
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     pos2 = positions[:, None]  # (S, 1) per-row positions for RoPE
 
-    q = dense(x, p["wq"], cfg, p.get("bq")).reshape(s, 1, h, hd)
-    k = dense(x, p["wk"], cfg, p.get("bk")).reshape(s, 1, kvh, hd)
-    v = dense(x, p["wv"], cfg, p.get("bv")).reshape(s, 1, kvh, hd)
+    q = dense(x, p["wq"], cfg, p.get("bq"), ctx=ctx).reshape(s, 1, h, hd)
+    k = dense(x, p["wk"], cfg, p.get("bk"), ctx=ctx).reshape(s, 1, kvh, hd)
+    v = dense(x, p["wv"], cfg, p.get("bv"), ctx=ctx).reshape(s, 1, kvh, hd)
     q = apply_rope(q, pos2, cfg.rope_theta)
     k = apply_rope(k, pos2, cfg.rope_theta)
     q = ctx.c(q, ("batch", "seq", "heads", "head_dim"))
 
-    ps = k_pool.shape[1]
-    pool_shape = k_pool.shape
-    page = jnp.take_along_axis(block_tables, (positions // ps)[:, None], axis=1)
-    flat = page[:, 0] * ps + positions % ps  # (S,) rows in the (P*ps, ...) view
-    k_pool = (
-        k_pool.reshape(-1, kvh, hd)
-        .at[flat]
-        .set(k[:, 0].astype(k_pool.dtype))
-        .reshape(pool_shape)
-    )
-    v_pool = (
-        v_pool.reshape(-1, kvh, hd)
-        .at[flat]
-        .set(v[:, 0].astype(v_pool.dtype))
-        .reshape(pool_shape)
-    )
+    ps = k_pool.shape[2]
+    page = jnp.take_along_axis(block_tables, (positions // ps)[:, None], axis=1)[:, 0]
+    off = positions % ps
+    # (S,) page ids and (S,) in-page offsets around the kv-head slice: each
+    # slot writes its (KV, hd) row into page[s][:, off[s]].
+    k_pool = k_pool.at[page, :, off].set(k[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[page, :, off].set(v[:, 0].astype(v_pool.dtype))
 
     out = paged_attention(
         q.reshape(s, h, hd),
@@ -217,7 +208,7 @@ def attention_paged_decode(
         interpret=interpret,
     ).reshape(s, 1, h, hd)
     out = ctx.c(out, ("batch", "seq", "heads", "head_dim"))
-    y = dense(out.reshape(s, 1, h * hd), p["wo"], cfg)
+    y = dense(out.reshape(s, 1, h * hd), p["wo"], cfg, ctx=ctx)
     return ctx.c(y, ("batch", "seq", "embed")), (k_pool, v_pool)
 
 
@@ -249,10 +240,10 @@ def attention(
         positions = jnp.arange(t)[None, :] + (cache_pos if cache_pos is not None else 0)
         positions = jnp.broadcast_to(positions, (b, t))
 
-    q = dense(x, p["wq"], cfg, p.get("bq")).reshape(b, t, h, hd)
+    q = dense(x, p["wq"], cfg, p.get("bq"), ctx=ctx).reshape(b, t, h, hd)
     if cross_kv is None:
-        k = dense(x, p["wk"], cfg, p.get("bk")).reshape(b, t, kvh, hd)
-        v = dense(x, p["wv"], cfg, p.get("bv")).reshape(b, t, kvh, hd)
+        k = dense(x, p["wk"], cfg, p.get("bk"), ctx=ctx).reshape(b, t, kvh, hd)
+        v = dense(x, p["wv"], cfg, p.get("bv"), ctx=ctx).reshape(b, t, kvh, hd)
         if use_rope:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
@@ -298,5 +289,5 @@ def attention(
             }
 
     out = ctx.c(out, ("batch", "seq", "heads", "head_dim"))
-    y = dense(out.reshape(b, t, h * hd), p["wo"], cfg)
+    y = dense(out.reshape(b, t, h * hd), p["wo"], cfg, ctx=ctx)
     return ctx.c(y, ("batch", "seq", "embed")), new_cache

@@ -11,12 +11,15 @@ All GEMMs go through the plan/execute API (`repro.kernels.api`): `gemm`
 builds a typed GemmSpec, `api.plan` resolves the backend against declared
 capabilities ONCE per logical shape (cfg.use_mesh_kernel selects the Pallas
 mesh kernel), and the cached plan executes per call; under pjit the XLA
-backend is used and sharding constraints carry the TP layout.
+backend is used and sharding constraints carry the TP layout.  GSPMD cannot
+partition a Pallas (Mosaic) kernel, so under a device mesh the kernel paths
+run data-parallel through shard_map instead (`ShardCtx.batch_axes`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -107,6 +110,17 @@ class ShardCtx:
             x, named_sharding(tuple(axes), self.mesh, rules, shape=x.shape)
         )
 
+    def batch_axes(self, rows: int):
+        """The mesh axes the rules give 'batch' when they divide `rows` (a
+        leading dim), else None: where a Pallas kernel's rows go under
+        shard_map."""
+        from repro.parallel.sharding import DEFAULT_RULES, _axes_on_mesh
+
+        axes = _axes_on_mesh(self.mesh, (self.rules or DEFAULT_RULES).get("batch"))
+        names = (axes,) if isinstance(axes, str) else tuple(axes or ())
+        size = math.prod(self.mesh.shape[a] for a in names)
+        return axes if names and rows % size == 0 else None
+
 
 NO_SHARD = ShardCtx()
 
@@ -133,6 +147,7 @@ def gemm(
     residual: Optional[jax.Array] = None,
     mesh: Any = None,
     shard: Any = None,
+    ctx: Optional[ShardCtx] = None,
 ) -> jax.Array:
     """Config-routed GEMM via plan/execute: XLA dot under pjit, Pallas mesh
     kernel if selected.
@@ -148,9 +163,16 @@ def gemm(
     With `shard` (a `kernels.api.ShardSpec`) and its live device `mesh`, the
     plan is a ShardedPlan: the same per-shard kernel lowered through
     shard_map with the ShardSpec's collective schedule — operands/results
-    stay global arrays, so call sites do not change shape-wise.
+    stay global arrays, so call sites do not change shape-wise.  A `ctx`
+    carrying a mesh gives the Pallas path that ShardSpec itself: rows over
+    the batch axes, weights whole (GSPMD cannot partition the kernel).
     """
     backend = "pallas_mesh" if getattr(cfg, "use_mesh_kernel", False) else "xla"
+    if shard is None and backend != "xla" and ctx is not None and ctx.mesh is not None:
+        mesh = ctx.mesh
+        shard = _api.ShardSpec.from_mesh(
+            mesh, m=ctx.batch_axes(x.shape[0]), schedule="replicated"
+        )
     blocks = (
         getattr(cfg, "mesh_block_m", 0) or None,
         getattr(cfg, "mesh_block_n", 0) or None,
@@ -229,11 +251,12 @@ def dense(
     residual: Optional[jax.Array] = None,
     mesh: Any = None,
     shard: Any = None,
+    ctx: Optional[ShardCtx] = None,
 ) -> jax.Array:
     """Dense projection with the fused epilogue: one kernel on the mesh path."""
     return gemm(
         x, w, cfg, bias=b, activation=activation, residual=residual,
-        mesh=mesh, shard=shard,
+        mesh=mesh, shard=shard, ctx=ctx,
     )
 
 

@@ -47,11 +47,11 @@ def swiglu_specs(cfg, d_ff: int) -> Dict[str, PSpec]:
 
 
 def swiglu(p: Dict[str, jax.Array], x: jax.Array, cfg, ctx: ShardCtx) -> jax.Array:
-    gate_up = gemm(x, p["wi"], cfg)
+    gate_up = gemm(x, p["wi"], cfg, ctx=ctx)
     gate_up = ctx.c(gate_up, ("batch", "seq", "mlp"))
     gate, up = jnp.split(gate_up, 2, axis=-1)
     h = jax.nn.silu(gate) * up
-    y = gemm(h, p["wo"], cfg)
+    y = gemm(h, p["wo"], cfg, ctx=ctx)
     return ctx.c(y, ("batch", "seq", "embed"))
 
 
@@ -157,11 +157,16 @@ def moe_block(
         # shared_gate rides the plan/execute API like every other projection
         # (f32 operands preserve the fp32-router numerics of the gate).
         sg = jax.nn.sigmoid(
-            gemm(xf.astype(jnp.float32), p["shared_gate"].astype(jnp.float32), cfg)
+            gemm(
+                xf.astype(jnp.float32),
+                p["shared_gate"].astype(jnp.float32),
+                cfg,
+                ctx=ctx,
+            )
         ).astype(x.dtype)
-        gu = gemm(xf, p["shared_wi"], cfg)
+        gu = gemm(xf, p["shared_wi"], cfg, ctx=ctx)
         g_, u_ = jnp.split(gu, 2, axis=-1)
-        shared = gemm(jax.nn.silu(g_) * u_, p["shared_wo"], cfg)
+        shared = gemm(jax.nn.silu(g_) * u_, p["shared_wo"], cfg, ctx=ctx)
         y = y + (shared * sg).reshape(b, t, d)
 
     # Switch load-balance + router z-loss (means over all tokens).  The

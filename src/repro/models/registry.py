@@ -93,7 +93,7 @@ class Model:
         self,
         params,
         tokens,  # (S, 1)
-        pools,  # {"k","v"}: (L, P, page_size, KV, hd)
+        pools,  # {"k","v"}: (L, P, KV, page_size, hd)
         block_tables,  # (S, n_pages)
         positions,  # (S,)
         ctx: ShardCtx = ShardCtx(),

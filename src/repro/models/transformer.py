@@ -22,6 +22,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.scramble import scramble_order
 from repro.kernels.ops import scramble_blocks
@@ -123,7 +124,7 @@ def embed_tokens(params, tokens: jax.Array, cfg, ctx: ShardCtx) -> jax.Array:
 def unembed(params, x: jax.Array, cfg, ctx: ShardCtx) -> jax.Array:
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = gemm(x, head.astype(x.dtype), cfg)
+    logits = gemm(x, head.astype(x.dtype), cfg, ctx=ctx)
     # Padded vocab rows (vocab_pad_multiple) never win loss/argmax.
     if head.shape[-1] != cfg.vocab_size:
         mask = jnp.arange(head.shape[-1]) < cfg.vocab_size
@@ -143,21 +144,34 @@ def _remat(fn, policy: str):
     raise ValueError(f"unknown remat policy {policy!r}")
 
 
-def _maybe_scramble(x: jax.Array, cfg, inverse: bool = False) -> jax.Array:
-    """Paper scrambling system on (T, D) activation block grids (square only)."""
+def _maybe_scramble(
+    x: jax.Array, cfg, ctx: ShardCtx, inverse: bool = False
+) -> jax.Array:
+    """Paper scrambling system on (T, D) activation block grids (square only).
+
+    Under a mesh the Pallas kernel runs on each device's share of the batch
+    (GSPMD cannot partition it)."""
     if not cfg.scramble_privacy:
         return x
     t, d = x.shape[-2], x.shape[-1]
     bm, bn = 128, 128
     if t % bm or d % bn or t // bm != d // bn:
         return x  # non-square grid: scrambling skipped (demo feature)
-    return scramble_blocks(x, block_m=bm, block_n=bn, k=-1 if inverse else 1)
+    fn = functools.partial(
+        scramble_blocks, block_m=bm, block_n=bn, k=-1 if inverse else 1
+    )
+    if ctx.mesh is None:
+        return fn(x)
+    rows = P(ctx.batch_axes(x.shape[0]))
+    return jax.shard_map(
+        fn, mesh=ctx.mesh, in_specs=rows, out_specs=rows, check_vma=False
+    )(x)
 
 
 def lm_forward(params, tokens: jax.Array, cfg, ctx: ShardCtx = ShardCtx()):
     """Train/eval forward: (B, T) int32 -> (logits (B, T, V), aux dict)."""
     x = embed_tokens(params, tokens, cfg, ctx)
-    x = _maybe_scramble(x, cfg)
+    x = _maybe_scramble(x, cfg, ctx)
 
     def body(x, lp):
         y, _, aux = block_apply(lp, x, cfg, ctx)
@@ -170,7 +184,7 @@ def lm_forward(params, tokens: jax.Array, cfg, ctx: ShardCtx = ShardCtx()):
 
     body = _remat(body, cfg.remat_policy)
     x, aux_stack = jax.lax.scan(body, x, params["blocks"], unroll=cfg.scan_unroll)
-    x = _maybe_scramble(x, cfg, inverse=True)
+    x = _maybe_scramble(x, cfg, ctx, inverse=True)
     logits = unembed(params, x, cfg, ctx)
     aux = {"lb_loss": aux_stack[:, 0].mean(), "router_z": aux_stack[:, 1].mean()}
     return logits, aux
@@ -247,7 +261,7 @@ def block_apply_paged(
 def lm_decode_paged(
     params,
     tokens: jax.Array,  # (S, 1) — one token per sequence slot
-    pools,  # {"k","v"}: (L, P, page_size, KV, hd) shared page pools
+    pools,  # {"k","v"}: (L, P, KV, page_size, hd) shared page pools
     block_tables: jax.Array,  # (S, n_pages) int32
     positions: jax.Array,  # (S,) int32 per-slot lengths
     cfg,
@@ -287,7 +301,7 @@ def lm_decode_paged(
 def paged_pool_specs(cfg, num_pages: int, page_size: int):
     """Abstract stacked page pools for the serving scheduler (one per layer)."""
     kv, hd = cfg.num_kv_heads, cfg.head_dim_
-    shp = (cfg.num_layers, num_pages, page_size, kv, hd)
+    shp = (cfg.num_layers, num_pages, kv, page_size, hd)
     return {
         "k": jax.ShapeDtypeStruct(shp, cfg.adtype),
         "v": jax.ShapeDtypeStruct(shp, cfg.adtype),

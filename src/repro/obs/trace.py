@@ -84,14 +84,13 @@ _TRACE_CLEAN: Optional[Callable[[], bool]] = None
 
 
 def _resolve_trace_clean() -> Callable[[], bool]:
+    # JAX keeps this predicate internal; an import failure after an upgrade
+    # must raise here rather than silently switch the in-jit guard off.
     global _TRACE_CLEAN
     if _TRACE_CLEAN is None:
-        try:
-            from jax.core import trace_state_clean as _clean  # type: ignore
+        from jax._src.core import trace_state_clean
 
-            _TRACE_CLEAN = _clean
-        except Exception:  # no jax in this process: never inside a trace
-            _TRACE_CLEAN = lambda: True
+        _TRACE_CLEAN = trace_state_clean
     return _TRACE_CLEAN
 
 

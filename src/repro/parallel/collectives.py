@@ -53,18 +53,6 @@ def _shift(p: int, by: int = 1):
     return [(s, (s - by) % p) for s in range(p)]
 
 
-def _axis_size(axis) -> int:
-    """Static named-axis size: jax >= 0.6 has jax.lax.axis_size; on 0.4.x
-    jax.core.axis_frame(name) returns the size directly."""
-    names = axis if isinstance(axis, (tuple, list)) else (axis,)
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(names)
-    size = 1
-    for name in names:
-        size *= jax.core.axis_frame(name)
-    return size
-
-
 def ring_allgather_matmul(
     x_blk: jax.Array,
     w: jax.Array,
@@ -104,7 +92,7 @@ def ring_allgather_matmul(
     sched = "allgather_a_overlap" if overlap else "allgather_a"
     faults.check("collective.step", schedule=sched, axis=axis)
     mm = matmul or _default_mm
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     m_blk, n = x_blk.shape[0], w.shape[1]
     out = jnp.zeros((p * m_blk, n), dtype=jnp.promote_types(x_blk.dtype, jnp.float32))
@@ -166,7 +154,7 @@ def matmul_ring_reducescatter(
     sched = "reduce_scatter_k_overlap" if overlap else "reduce_scatter_k"
     faults.check("collective.step", schedule=sched, axis=axis)
     mm = matmul or _default_mm
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     m, n = x.shape[0], w_blk.shape[1]
     if m % p:
@@ -231,7 +219,7 @@ def ring_pipeline_matmul(
 
     faults.check("collective.step", schedule="pipeline", axis=axis)
     mm = matmul or _default_mm
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     m, n = x.shape[0], w_blk.shape[1]
     if microbatches % p or microbatches <= 0:
@@ -278,7 +266,7 @@ def ring_pipeline_matmul(
 def psum_if_multi(x: jax.Array, axis: str) -> jax.Array:
     """psum that is a no-op on a missing/size-1 axis (mesh-shape agnostic)."""
     try:
-        size = _axis_size(axis)
+        size = jax.lax.axis_size(axis)
     except NameError:
         return x
     return jax.lax.psum(x, axis) if size > 1 else x

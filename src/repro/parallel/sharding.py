@@ -31,6 +31,7 @@ import warnings
 from typing import Any, Mapping, Optional, Sequence, Tuple
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
@@ -121,23 +122,6 @@ TRAIN_RULES = DEFAULT_RULES.replace(seq_sp="model")
 SP_DECODE_RULES = DEFAULT_RULES.replace(
     kv_seq=("pod", "data"), kv_batch=None, batch=None
 )
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True, **kw):
-    """Version-compat shard_map: jax >= 0.5 exposes `jax.shard_map` with
-    `check_vma`; 0.4.x has `jax.experimental.shard_map.shard_map` with the
-    same flag spelled `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kw,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map  # noqa: PLC0415
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, **kw,
-    )
 
 
 def _axes_on_mesh(mesh: Mesh, axes):

@@ -139,13 +139,13 @@ def ring_systolic_kpass(
     bitwise (a half-width `matmul` kernel hook may retile, so the general
     oracle is exactness on integer-valued data).
     """
-    from repro.parallel.collectives import _axis_size, _default_mm, _shift
+    from repro.parallel.collectives import _default_mm, _shift
     from repro.resilience import faults
 
     sched = "ring_k_overlap" if overlap else "ring_k"
     faults.check("collective.step", schedule=sched, axis=axis)
     mm = matmul or _default_mm
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     n = b_blk.shape[1]
     if not overlap or p == 1 or n < 2:
         part = mm(a_blk, b_blk)

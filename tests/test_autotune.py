@@ -34,9 +34,9 @@ def test_cache_key_formalizes_legacy_format():
 
 
 def test_vmem_model_counts_tiles_and_acc():
-    # A-tile + B-tile in dtype + f32 accumulator
-    assert vmem_bytes(128, 128, 128, jnp.bfloat16) == (128 * 128 * 2) * 2 + 128 * 128 * 4
-    assert vmem_bytes(128, 128, 128, jnp.float32) == (128 * 128 * 4) * 2 + 128 * 128 * 4
+    # double-buffered A, B and output tiles in dtype + one f32 accumulator
+    assert vmem_bytes(128, 128, 128, jnp.bfloat16) == 2 * 3 * (128 * 128 * 2) + 128 * 128 * 4
+    assert vmem_bytes(128, 128, 128, jnp.float32) == 2 * 3 * (128 * 128 * 4) + 128 * 128 * 4
     plain = vmem_bytes(128, 128, 128, jnp.bfloat16)
     assert vmem_bytes(128, 128, 128, jnp.bfloat16, has_residual=True) > plain
     assert vmem_bytes(128, 128, 128, jnp.bfloat16, has_bias=True) > plain
@@ -146,6 +146,51 @@ def test_timed_search_picks_fastest_and_persists(cache):
     reloaded = AutotuneCache(cache.path)
     key = cache_key(512, 512, 512, jnp.bfloat16, "pallas_mesh")
     assert reloaded.get(key) == (256, 256, 128)
+
+
+def test_timed_search_runs_concretely_inside_a_trace(cache):
+    """Plans are built while a model step is traced; the timed search must
+    still run each candidate on concrete arrays, not stage tracers."""
+    import jax
+
+    concrete = []
+
+    def measure(m, k, n, dtype, backend, blocks):
+        x = jnp.zeros((8, 128), dtype) + 1
+        concrete.append(not isinstance(x, jax.core.Tracer))
+        return float(blocks[0])
+
+    def step(x):
+        autotune.autotune(
+            512, 512, 512, jnp.bfloat16, "pallas_mesh",
+            cache=cache, mode="time", measure=measure, max_timed=3,
+        )
+        return x + 1
+
+    jax.jit(step)(jnp.ones(2))
+    assert concrete and all(concrete)
+
+
+def test_timed_search_runs_the_kernel_inside_a_trace(cache):
+    """The default measure launches the real kernel (interpret mode here)
+    from inside a traced step; no candidate may fail there."""
+    import jax
+
+    from repro.resilience import ledger
+
+    ledger.clear()
+
+    def step(x):
+        autotune.autotune(
+            256, 256, 256, jnp.float32, "pallas_mesh",
+            cache=cache, mode="time", max_timed=2,
+        )
+        return x + 1
+
+    jax.jit(step)(jnp.ones(2))
+    assert ledger.count() == 0, ledger.format_summary()
+    key = cache_key(256, 256, 256, jnp.float32, "pallas_mesh")
+    assert cache._load()[key]["source"] == "timed"
 
 
 def test_warm_start_is_tried_first(cache):

@@ -30,7 +30,7 @@ from repro.costmodel.model import (
 from repro.kernels import api
 from repro.kernels.api import Epilogue, GemmSpec, GroupSpec, ShardSpec
 
-from tests._hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 B = 8
 
@@ -437,3 +437,15 @@ def test_render_markdown_rows_and_skips():
     assert "OOM" in md and "too big" in md
     # no title -> header first
     assert render_markdown(rows).startswith("| arch ")
+
+
+def test_tpu_peaks_are_keyed_by_device_kind():
+    from repro.costmodel.model import TPU_PEAKS, tpu_peaks
+
+    assert tpu_peaks("TPU v5 lite") == (197e12, 819e9, 50e9)
+    co = default_coefficients("tpu", "TPU v5 lite")
+    assert (co.flops_per_s, co.hbm_bytes_per_s) == TPU_PEAKS["TPU v5 lite"][:2]
+    with pytest.raises(ValueError, match="no published peaks"):
+        default_coefficients("tpu", "TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no published peaks"):
+        default_coefficients("tpu")

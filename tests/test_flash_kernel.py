@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.models.attention import _sdpa, _sdpa_chunked

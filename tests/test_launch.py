@@ -171,7 +171,7 @@ def test_dryrun_cell_on_small_production_slice():
         import repro.launch.mesh as mesh_mod
         import jax
         real = mesh_mod.make_production_mesh
-        mesh_mod.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
+        mesh_mod.make_production_mesh = lambda multi_pod=False: mesh_mod._make_mesh(
             (4, 4), ("data", "model"))
         from repro.launch.dryrun import run_cell
         art = run_cell("rwkv6-1.6b", "decode_32k", probe=False, verbose=False)
@@ -223,7 +223,7 @@ def test_probe_correction_matches_ground_truth():
         import dataclasses
         import jax
         import repro.launch.mesh as mesh_mod
-        mesh_mod.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
+        mesh_mod.make_production_mesh = lambda multi_pod=False: mesh_mod._make_mesh(
             (4, 2), ("data", "model"))
         from repro.configs import SHAPES, get_config
         from repro.launch.dryrun import (
@@ -268,7 +268,7 @@ def test_multipod_mesh_cell_with_pod_axis():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         import repro.launch.mesh as mesh_mod
-        mesh_mod.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
+        mesh_mod.make_production_mesh = lambda multi_pod=False: mesh_mod._make_mesh(
             (2, 2, 2), ("pod", "data", "model"))
         from repro.launch.dryrun import run_cell
         art = run_cell("zamba2-1.2b", "decode_32k", multi_pod=True,
@@ -286,3 +286,31 @@ def test_multipod_mesh_cell_with_pod_axis():
     )
     assert out.returncode == 0, out.stderr[-3000:]
     assert "OK" in out.stdout
+
+
+# --- persistent compilation cache ------------------------------------------------
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only cache; otherwise the
+    entry points use <repo>/.jax_cache.  A fresh process, since the helper
+    changes process-wide JAX config."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(root, ".jax_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    prog = (
+        "import jax\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "got = enable_compile_cache()\n"
+        "print(got, jax.config.jax_compilation_cache_dir)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", prog], cwd=root, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]

@@ -11,7 +11,7 @@ Paper claims validated here:
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.mesh_array import (
     mesh_completion_times,

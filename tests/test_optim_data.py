@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.pipeline import DataConfig, SyntheticLM, pack_documents
 from repro.optim import AdamWConfig, constant, warmup_cosine

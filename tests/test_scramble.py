@@ -11,7 +11,7 @@ import math
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import scramble
 from repro.core.scramble import (

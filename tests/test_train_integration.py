@@ -99,6 +99,20 @@ def test_serve_cli_smoke(capsys):
     assert "decode steps/s" in out
 
 
+def test_serve_cli_exits_nonzero_when_a_request_is_skipped(capsys):
+    from repro.launch.serve import main
+    from repro.resilience import faults, ledger
+
+    ledger.clear()
+    with faults.inject({"serve.request": faults.FaultSpec(times=1)}):
+        with pytest.raises(SystemExit, match="1 of 2 requests failed"):
+            main(["--arch", "rwkv6-1.6b", "--reduced", "--batch", "2",
+                  "--prompt-len", "8", "--gen", "4", "--requests", "2"])
+    out = capsys.readouterr().out
+    assert "request 0 FAILED" in out and "req 1: decode steps/s" in out
+    ledger.clear()
+
+
 def test_mesh_kernel_backend_trains():
     """cfg.use_mesh_kernel: the paper's Pallas GEMM backend in a real
     train step (interpret mode on CPU), gradients flowing through the
