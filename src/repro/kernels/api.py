@@ -1311,21 +1311,15 @@ class Plan:
         `__call__` costs minus any value read; the point is the explicit
         contract: validation and enqueue happen NOW, device work proceeds in
         the background, and `AsyncResult.block()` (or `execute_async` over a
-        batch of independent plans) is the single sync point.  The enqueue
-        runs under its own `plan.dispatch` obs span — NOT `plan.execute`,
-        whose warm spans feed cost-model calibration and must measure device
-        walltime, not host enqueue time.  Caveat: a plan with a
-        `guard_nonfinite` policy host-syncs inside execution to inspect the
-        output, so its dispatch is effectively synchronous (the guard wins).
+        batch of independent plans) is the single sync point.  No
+        `plan.execute` span is opened: its warm spans feed cost-model
+        calibration and must measure device walltime, not host enqueue
+        time.  Caveat: a plan with a `guard_nonfinite` policy host-syncs
+        inside execution to inspect the output, so its dispatch is
+        effectively synchronous (the guard wins).
         """
         self._check_operands(a, b, bias, residual)
-        args = (a, b, bias, residual)
-        if _obs._STATE.enabled:
-            with _obs.span("plan.dispatch", **self._obs_attrs()):
-                out = self._execute_impl(args)
-        else:
-            out = self._execute_impl(args)
-        return AsyncResult(self, out)
+        return AsyncResult(self, self._execute_impl((a, b, bias, residual)))
 
     # -- resilience (DESIGN.md §11) ------------------------------------------
 

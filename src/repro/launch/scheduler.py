@@ -179,6 +179,8 @@ class _Seq:
     admit_tick: int
     submitted_tick: int
     submitted_at: float
+    first_at: float  # host clock when the first token reached the host
+    last_at: float  # ... and when the latest one did
     stalled: bool = False  # page-alloc fault this tick: skip, retry next
 
 
@@ -233,9 +235,10 @@ class ContinuousBatchingServer:
         self._m_tokens = _metrics.counter(
             "serve_decode_tokens_total", "tokens produced by decode ticks")
         self._m_ttft = _metrics.histogram(
-            "serve_ttft_seconds", "submission -> first token latency")
+            "serve_ttft_seconds", "submission -> first token on the host")
         self._m_tpot = _metrics.histogram(
-            "serve_tpot_seconds", "per-tick decode wall time (time per token)")
+            "serve_tpot_seconds",
+            "served request's mean gap between tokens after its first")
 
         if self._paged:
             self.alloc = PageAllocator(cfg.num_pages)
@@ -364,6 +367,8 @@ class ContinuousBatchingServer:
                      submitted_tick=submitted_tick, submitted_at=submitted_at)
 
     def _evict(self, seq: _Seq, status: str, reason: str) -> None:
+        if status == "ok" and len(seq.tokens) > 1:
+            self._m_tpot.observe((seq.last_at - seq.first_at) / (len(seq.tokens) - 1))
         if self._paged and seq.pages:
             self.alloc.free(seq.pages)
             seq.pages = []  # retired sequences must never grow or double-free
@@ -377,20 +382,22 @@ class ContinuousBatchingServer:
 
     def submit(self, req: Request) -> None:
         """Enqueue a request; over-capacity and never-fits are shed NOW."""
-        now = time.monotonic()
-        if req.rid in self.results or any(
-            q.rid == req.rid for q, _, _ in self._queue
-        ) or any(s.req.rid == req.rid for s in self._active):
-            raise ValueError(f"duplicate request id {req.rid!r}")
-        reason = self._fits(req)
-        if reason is not None:
-            self._shed(req, reason, submitted_tick=self._tick, submitted_at=now)
-            return
-        if len(self._queue) >= self.cfg.queue_capacity:
-            self._shed(req, "queue_full", submitted_tick=self._tick,
-                       submitted_at=now)
-            return
-        self._queue.append((req, self._tick, now))
+        # The span starts with the request's submission time (`now`).
+        with _obs.span("serve.submit", rid=req.rid):
+            now = time.monotonic()
+            if req.rid in self.results or any(
+                q.rid == req.rid for q, _, _ in self._queue
+            ) or any(s.req.rid == req.rid for s in self._active):
+                raise ValueError(f"duplicate request id {req.rid!r}")
+            reason = self._fits(req)
+            if reason is not None:
+                self._shed(req, reason, submitted_tick=self._tick, submitted_at=now)
+                return
+            if len(self._queue) >= self.cfg.queue_capacity:
+                self._shed(req, "queue_full", submitted_tick=self._tick,
+                           submitted_at=now)
+                return
+            self._queue.append((req, self._tick, now))
 
     # -- the tick ------------------------------------------------------------
 
@@ -398,9 +405,9 @@ class ContinuousBatchingServer:
         """One scheduler tick: expire, admit, grow, decode, retire."""
         self._tick += 1
         self.counters["ticks"] += 1
-        # The per-tick span nests everything the tick does (admission
-        # prefills, the decode step) and costs one attribute check when
-        # tracing is off; exports flush at drain/exit, never here.
+        # The per-tick span nests everything the tick does, each piece of
+        # host work in a child span, and costs one attribute check per span
+        # when tracing is off; exports flush at drain/exit, never here.
         with _obs.span("serve.tick", tick=self._tick,
                        active=len(self._active), queued=len(self._queue)):
             try:
@@ -417,9 +424,11 @@ class ContinuousBatchingServer:
                 return
             self._m_ticks.inc(outcome="ok")
 
-            self._expire_deadlines()
+            with _obs.span("serve.expire"):
+                self._expire_deadlines()
             self._admit()
-            self._ensure_pages()
+            with _obs.span("serve.grow"):
+                self._ensure_pages()
             self._decode_tick()
 
     def _expire_deadlines(self) -> None:
@@ -478,29 +487,42 @@ class ContinuousBatchingServer:
 
             self._queue.pop(0)
             slot = self._free_slots.pop()
-            with _obs.span("serve.prefill", rid=req.rid, tokens=prefill_len):
-                first_tok, state = self._run_prefill(req)
-            self._m_admitted.inc()
-            # TTFT: submission -> first token (prefill emits it greedily).
-            self._m_ttft.observe(time.monotonic() - submitted_at)
-            if self._paged:
-                self.pools = self._scatter(
-                    self.pools, state, jnp.asarray(pages, jnp.int32)
-                )
-            else:
-                self.state = self._insert_state(
-                    self.state, state, jnp.int32(slot)
-                )
+            with _obs.span("serve.admit", rid=req.rid):
+                t_admit = time.monotonic()
+                with _obs.span("serve.prefill", rid=req.rid, tokens=prefill_len):
+                    first_tok, state = self._run_prefill(req)
+                self._m_admitted.inc()
+                with _obs.span("serve.scatter", rid=req.rid):
+                    if self._paged:
+                        self.pools = self._scatter(
+                            self.pools, state, jnp.asarray(pages, jnp.int32)
+                        )
+                    else:
+                        self.state = self._insert_state(
+                            self.state, state, jnp.int32(slot)
+                        )
+                # The prefill emits the first token greedily; it is on the
+                # host once this sync returns.
+                with _obs.span("serve.first_sync", rid=req.rid):
+                    tok = int(first_tok[0])
+                t_first = time.monotonic()
+            self._m_ttft.observe(t_first - submitted_at)
+            _obs.record(
+                "serve.first_token", submitted_at, t_first, rid=req.rid,
+                prompt_len=int(req.prompt.shape[0]), queued_s=t_admit - submitted_at,
+            )
             seq = _Seq(
                 req=req,
                 slot=slot,
                 pages=pages,
                 pos=prefill_len,
-                tokens=[int(first_tok[0])],
+                tokens=[tok],
                 deadline_tick=submitted_tick + self._deadline_ticks(req),
                 admit_tick=self._tick,
                 submitted_tick=submitted_tick,
                 submitted_at=submitted_at,
+                first_at=t_first,
+                last_at=t_first,
             )
             self._active.append(seq)
             if len(seq.tokens) >= req.max_new_tokens:
@@ -587,35 +609,36 @@ class ContinuousBatchingServer:
         return ready, tokens, positions, tables
 
     def _decode_tick(self) -> None:
-        ready, tokens, positions, tables = self.decode_inputs()
+        with _obs.span("serve.inputs"):
+            ready, tokens, positions, tables = self.decode_inputs()
         if not ready:
             return
-        # The decode span covers the jitted step AND the host sync
-        # (np.asarray blocks), so its duration is the honest per-tick
-        # decode wall time — the same number the tpot histogram records.
-        t0 = time.monotonic()
+        # The decode span covers the upload, the jitted step AND the host
+        # sync (np.asarray blocks): the tick's decode wall time.
         with _obs.span("serve.decode", slots=len(ready), tick=self._tick):
+            with _obs.span("serve.upload"):
+                tokens = jnp.asarray(tokens)
+                if self._paged:
+                    tables = jnp.asarray(tables)
+                    positions = jnp.asarray(positions)
             if self._paged:
                 nxt, self.pools = self._decode(
-                    self.params,
-                    jnp.asarray(tokens),
-                    self.pools,
-                    jnp.asarray(tables),
-                    jnp.asarray(positions),
+                    self.params, tokens, self.pools, tables, positions
                 )
             else:
-                nxt, self.state = self._decode(
-                    self.params, jnp.asarray(tokens), self.state
-                )
-            nxt = np.asarray(nxt)
-        self._m_tpot.observe(time.monotonic() - t0)
-        for seq in ready:
-            seq.tokens.append(int(nxt[seq.slot]))
-            seq.pos += 1
-            self.counters["decode_tokens"] += 1
-            self._m_tokens.inc()
-            if len(seq.tokens) >= seq.req.max_new_tokens:
-                self._evict(seq, "ok", "")
+                nxt, self.state = self._decode(self.params, tokens, self.state)
+            with _obs.span("serve.decode_sync"):
+                nxt = np.asarray(nxt)
+        now = time.monotonic()
+        self.counters["decode_tokens"] += len(ready)
+        self._m_tokens.inc(len(ready))
+        with _obs.span("serve.retire"):
+            for seq in ready:
+                seq.tokens.append(int(nxt[seq.slot]))
+                seq.last_at = now
+                seq.pos += 1
+                if len(seq.tokens) >= seq.req.max_new_tokens:
+                    self._evict(seq, "ok", "")
 
     # -- driving -------------------------------------------------------------
 
@@ -653,7 +676,7 @@ class ContinuousBatchingServer:
         # background while the prefill/decode warmups below build their own
         # traces; the handle is collected after.  The guarded canary
         # host-syncs inside execution anyway (documented dispatch caveat),
-        # but the call path exercises plan.dispatch on every serve startup.
+        # but the call path exercises `Plan.dispatch` on every serve startup.
         cold = canary.dispatch(a, a)
         cold.block()
         # Second execution is compile-free: when tracing is on, its

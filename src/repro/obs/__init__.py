@@ -50,11 +50,11 @@ from repro.obs.trace import (
     enable,
     is_enabled,
     on_span_end,
+    record,
     remove_span_end,
     span,
     spans,
     stats,
-    traced,
     tracing,
 )
 from repro.obs.trace import clear as clear_spans
@@ -84,6 +84,7 @@ __all__ = [
     "on_span_end",
     "pending_calibration_records",
     "prometheus_text",
+    "record",
     "remove_span_end",
     "reset_metrics",
     "snapshot",
@@ -91,7 +92,6 @@ __all__ = [
     "spans",
     "stats",
     "submit_calibration",
-    "traced",
     "tracing",
     "uninstall",
     "write_chrome_trace",

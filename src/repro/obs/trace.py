@@ -26,18 +26,27 @@ Design contract (DESIGN.md §14):
                    time and the span would fire once per compile, not per
                    execution.  When jax reports an active trace the span is
                    suppressed (counted in `stats()["suppressed_in_trace"]`).
+  one clock        while enabled, every span also opens a
+                   `jax.profiler.TraceAnnotation` of the same name (no
+                   `#k=v#` metadata), so a running profiler shows the spans
+                   on the host line beside the device's ops.
+  compiles         the first `enable()` registers one jax.monitoring
+                   listener that lands JAX's trace and backend-compile
+                   events (persistent-cache loads included) as `jit.compile`
+                   spans and counts them in `jit_compiles_total{phase}`.
 
-Zero dependencies: stdlib only; jax is imported lazily and only to ask
-"are we inside a trace?" — the module works in processes without jax.
+jax is imported lazily: to ask "are we inside a trace?", for the profiler
+annotation and for the compile listener.
 """
 
 from __future__ import annotations
 
 import collections
-import functools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+from repro.obs import metrics as _metrics
 
 __all__ = [
     "Span",
@@ -47,11 +56,11 @@ __all__ = [
     "enable",
     "is_enabled",
     "on_span_end",
+    "record",
     "remove_span_end",
     "span",
     "spans",
     "stats",
-    "traced",
     "tracing",
 ]
 
@@ -81,15 +90,26 @@ _STATS = {"started": 0, "finished": 0, "dropped": 0, "suppressed_in_trace": 0}
 
 # Resolved lazily at first enabled span: () -> bool, True when NOT tracing.
 _TRACE_CLEAN: Optional[Callable[[], bool]] = None
+# Resolved with it: jax.profiler.TraceAnnotation.
+_ANNOTATION: Optional[Callable[[str], Any]] = None
+_COMPILE_LISTENER = [False]  # registered with jax.monitoring (once)
+
+# JAX's compile events (jax._src.dispatch) and the phase each is recorded as.
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
 
 
 def _resolve_trace_clean() -> Callable[[], bool]:
     # JAX keeps this predicate internal; an import failure after an upgrade
     # must raise here rather than silently switch the in-jit guard off.
-    global _TRACE_CLEAN
+    global _TRACE_CLEAN, _ANNOTATION
     if _TRACE_CLEAN is None:
         from jax._src.core import trace_state_clean
+        from jax.profiler import TraceAnnotation
 
+        _ANNOTATION = TraceAnnotation
         _TRACE_CLEAN = trace_state_clean
     return _TRACE_CLEAN
 
@@ -98,7 +118,7 @@ class Span:
     """One finished-or-open interval.  Mutable while open (`set()` adds
     attributes mid-span); append-only once it lands in the ring."""
 
-    __slots__ = ("name", "seq", "parent", "tid", "t0", "t1", "attrs")
+    __slots__ = ("name", "seq", "parent", "tid", "t0", "t1", "attrs", "annotation")
 
     def __init__(self, name: str, seq: int, parent: Optional[int], tid: int,
                  t0: float, attrs: Dict[str, Any]):
@@ -109,6 +129,7 @@ class Span:
         self.t0 = t0
         self.t1 = t0
         self.attrs = attrs
+        self.annotation = None  # the open profiler annotation, if any
 
     @property
     def duration_s(self) -> float:
@@ -194,12 +215,17 @@ def _begin(name: str, attrs: Dict[str, Any]):
         seq = _SEQ[0]
         _STATS["started"] += 1
     sp = Span(name, seq, parent, threading.get_ident(), time.monotonic(), attrs)
+    sp.annotation = _ANNOTATION(name)
+    sp.annotation.__enter__()
     st.append(sp)
     return sp
 
 
 def _end_span(sp: Span, exc: Optional[BaseException]) -> None:
     sp.t1 = time.monotonic()
+    if sp.annotation is not None:
+        sp.annotation.__exit__(None, None, None)
+        sp.annotation = None
     if exc is not None:
         sp.attrs["error"] = f"{type(exc).__name__}: {exc}"
     st = _stack()
@@ -208,6 +234,29 @@ def _end_span(sp: Span, exc: Optional[BaseException]) -> None:
     if sp in st:
         while st and st.pop() is not sp:
             pass
+    _land(sp)
+
+
+def record(name: str, t0: float, t1: float, **attrs: Any) -> None:
+    """Land an already-finished span [t0, t1] (monotonic seconds) in the
+    ring, as a root, without touching the thread's span stack.
+
+    For intervals that no `with` block can hold: a request's wait for its
+    first token spans several ticks, and a compile is timed by JAX.  Such a
+    span opens no profiler annotation, since it is not one stretch of this
+    thread's work.  Disabled tracing records nothing."""
+    if not _STATE.enabled:
+        return
+    with _LOCK:
+        _SEQ[0] += 1
+        seq = _SEQ[0]
+        _STATS["started"] += 1
+    sp = Span(name, seq, None, threading.get_ident(), t0, attrs)
+    sp.t1 = t1
+    _land(sp)
+
+
+def _land(sp: Span) -> None:
     with _LOCK:
         _STATS["finished"] += 1
         if _RING.maxlen is not None and len(_RING) == _RING.maxlen:
@@ -221,36 +270,50 @@ def _end_span(sp: Span, exc: Optional[BaseException]) -> None:
             pass  # a broken hook must never take the traced path down
 
 
-def traced(name_or_fn=None, **attrs: Any):
-    """Decorator form: ``@traced`` or ``@traced("layer.verb", key=...)``."""
-
-    def deco(fn: Callable, name: Optional[str] = None) -> Callable:
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            if not _STATE.enabled:
-                return fn(*args, **kwargs)
-            with _begin(label, dict(attrs)):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    if callable(name_or_fn):
-        return deco(name_or_fn)
-    return lambda fn: deco(fn, name_or_fn)
-
-
 # ---------------------------------------------------------------------------
 # Switches + introspection
 # ---------------------------------------------------------------------------
 
 
 def enable(capacity: Optional[int] = None) -> None:
-    """Turn tracing on (optionally resizing the ring)."""
+    """Turn tracing on (optionally resizing the ring); the first call also
+    registers the compile listener."""
     if capacity is not None:
         configure(capacity=capacity)
+    _listen_for_compiles()
     _STATE.enabled = True
+
+
+def _compile_counter() -> "_metrics.Counter":
+    return _metrics.counter(
+        "jit_compiles_total", "JAX compile events while tracing was on",
+        labels=("phase",),
+    )
+
+
+def _listen_for_compiles() -> None:
+    _compile_counter()  # present (at 0) wherever compiles are counted
+    with _LOCK:
+        if _COMPILE_LISTENER[0]:
+            return
+        _COMPILE_LISTENER[0] = True
+    from jax import monitoring
+
+    monitoring.register_event_time_span_listener(_on_compile_event)
+
+
+def _on_compile_event(event: str, start_time: float, end_time: float, **kwargs: Any) -> None:
+    """JAX's compile events (wall-clock `time.time()` stamps) as `jit.compile`
+    spans on the ring's monotonic clock, attrs `fun` and `phase`."""
+    if not _STATE.enabled:
+        return
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    shift = time.monotonic() - time.time()
+    record("jit.compile", start_time + shift, end_time + shift,
+           fun=str(kwargs.get("fun_name", "")), phase=phase)
+    _compile_counter().inc(phase=phase)
 
 
 def disable() -> None:

@@ -114,21 +114,60 @@ def test_span_records_error_and_unwinds():
     assert obs.spans("t.after")[0].parent is None
 
 
-def test_traced_decorator():
-    calls = []
-
-    @obs.traced("t.deco", kind="unit")
-    def fn(x):
-        calls.append(x)
-        return x + 1
-
-    assert fn(1) == 2  # disabled: no span, function still runs
-    assert obs.spans("t.deco") == []
+def test_record_lands_a_finished_span_off_the_stack():
+    assert obs.record("t.rec", 1.0, 2.0, k=1) is None
+    assert obs.spans("t.rec") == []  # disabled: nothing
     obs.enable()
-    assert fn(2) == 3
-    (sp,) = obs.spans("t.deco")
-    assert sp.attrs == {"kind": "unit"}
-    assert calls == [1, 2]
+    with obs.span("t.open") as open_:
+        obs.record("t.rec", 1.0, 2.5, k=1)
+        with obs.span("t.child"):
+            pass
+    (rec,) = obs.spans("t.rec")
+    assert (rec.t0, rec.t1, rec.duration_s, rec.attrs) == (1.0, 2.5, 1.5, {"k": 1})
+    assert rec.parent is None and rec.annotation is None
+    # the open span's stack was left alone: the child still nests under it
+    assert obs.spans("t.child")[0].parent == open_.seq
+
+
+def test_fresh_jit_records_one_compile_span_per_phase():
+    def fresh_compile_probe(x):
+        return jax.lax.sin(x)
+
+    obs.enable()
+    f = jax.jit(fresh_compile_probe)
+    t_before = time.monotonic()
+    f(jnp.ones(3)).block_until_ready()
+    t_after = time.monotonic()
+    mine = [s for s in obs.spans("jit.compile") if "fresh_compile_probe" in s.attrs["fun"]]
+    assert sorted(s.attrs["phase"] for s in mine) == ["backend_compile", "trace"]
+    for s in mine:  # JAX's wall-clock stamps, moved to the ring's clock
+        assert t_before - 0.05 <= s.t0 <= s.t1 <= t_after + 0.05
+    c = obs.counter("jit_compiles_total", labels=("phase",))
+    assert c.value(phase="backend_compile") >= 1 and c.value(phase="trace") >= 1
+    n = len(obs.spans("jit.compile"))
+    f(jnp.ones(3)).block_until_ready()  # cached: no compile
+    assert len(obs.spans("jit.compile")) == n
+
+
+def test_no_compile_span_while_disabled():
+    obs.enable()
+    obs.disable()  # the listener stays registered, and records nothing
+
+    def disabled_compile_probe(x):
+        return jax.lax.cos(x)
+
+    jax.jit(disabled_compile_probe)(jnp.ones(3)).block_until_ready()
+    assert obs.spans("jit.compile") == []
+    assert obs.counter("jit_compiles_total", labels=("phase",)).total() == 0
+
+
+def test_spans_open_profiler_annotations_only_while_enabled():
+    with obs.span("t.off") as sp:
+        assert sp is obs_trace._NULL
+    obs.enable()
+    with obs.span("t.on") as sp:
+        assert sp.annotation is not None
+    assert sp.annotation is None  # closed with the span
 
 
 def test_ring_is_bounded_and_counts_drops():
@@ -434,9 +473,46 @@ def test_scheduler_ticks_emit_spans_and_metrics(dense, tmp_path, monkeypatch):
     assert obs.counter("serve_decode_tokens_total").value() == float(
         server.counters["decode_tokens"]
     )
+    # ...and once per tick, not per token
+    assert obs.counter("serve_decode_tokens_total").value() == 3 * 3
     h = obs.histogram("serve_ttft_seconds")
     assert h.count() == 3 and h.quantile(0.5) > 0
-    assert obs.histogram("serve_tpot_seconds").count() == len(decodes)
+    # one mean gap after the first token per served request, not per tick
+    tpot = obs.histogram("serve_tpot_seconds")
+    assert tpot.count() == 3 and 0 < tpot.sum() / 3 < max(s.duration_s for s in ticks) * 2
+    # the tick's host work sits in child spans of the tick
+    for name in ("serve.expire", "serve.grow", "serve.inputs", "serve.retire"):
+        assert {s.parent for s in obs.spans(name)} <= tick_seqs, name
+    admits = {s.seq for s in obs.spans("serve.admit")}
+    for name in ("serve.prefill", "serve.scatter", "serve.first_sync"):
+        assert {s.parent for s in obs.spans(name)} == admits, name
+    decode_seqs = {s.seq for s in decodes}
+    for name in ("serve.upload", "serve.decode_sync"):
+        assert {s.parent for s in obs.spans(name)} == decode_seqs, name
+
+
+def test_scheduler_records_first_token_per_admission(dense):
+    from repro.launch.scheduler import Request
+
+    obs.enable()
+    server = _mk_server(dense)
+    server.warmup()
+    for r in range(3):
+        server.submit(Request(rid=f"r{r}", prompt=np.zeros(8 + r, np.int32), max_new_tokens=3))
+    server.drain()
+    firsts = {s.attrs["rid"]: s for s in obs.spans("serve.first_token")}
+    assert set(firsts) == {"r0", "r1", "r2"}
+    submits = {s.attrs["rid"]: s for s in obs.spans("serve.submit")}
+    syncs = {s.attrs["rid"]: s for s in obs.spans("serve.first_sync")}
+    for rid, sp in firsts.items():
+        assert sp.attrs["prompt_len"] == 8 + int(rid[1])
+        assert 0 <= sp.attrs["queued_s"] <= sp.duration_s
+        assert submits[rid].t0 <= sp.t0 <= submits[rid].t1  # from the submission
+        assert syncs[rid].t1 <= sp.t1  # to the first token on the host
+    # the TTFT histogram observes exactly these durations
+    h = obs.histogram("serve_ttft_seconds")
+    assert h.count() == 3
+    assert h.sum() == pytest.approx(sum(s.duration_s for s in firsts.values()), rel=1e-12)
 
 
 def test_serve_main_obs_export_end_to_end(tmp_path, capsys, monkeypatch):

@@ -14,16 +14,23 @@ process may hold the TPU library, and every test worker imports this file.
 """
 
 import functools
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.grouped import grouped_mesh_matmul_pallas
-from repro.kernels.mesh_matmul import mesh_matmul_pallas, mesh_matmul_pallas_batched
-from repro.kernels.paged_attention import paged_attention_pallas
-from repro.kernels.scramble_kernel import scramble_blocks_pallas
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from bench.trace_reduce import op_family  # noqa: E402
+from repro.kernels.grouped import grouped_mesh_matmul_pallas  # noqa: E402
+from repro.kernels.mesh_matmul import mesh_matmul_pallas, mesh_matmul_pallas_batched  # noqa: E402
+from repro.kernels.paged_attention import paged_attention_pallas  # noqa: E402
+from repro.kernels.scramble_kernel import scramble_blocks_pallas  # noqa: E402
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -133,3 +140,36 @@ def test_paged_attention(one_chip, page_size):
         ((slots,), I32),
     )
     _assert_kernel(hlo)
+
+
+# The device trace names an op by its HLO instruction; the benchmark's
+# readers match these families (bench/trace_reduce.op_family), so a rename
+# (a `name=` on a pallas_call, another wrapper) must keep them.
+_NAMED_KERNELS = {
+    "mesh_matmul_pallas": (
+        functools.partial(mesh_matmul_pallas, block_m=256, block_n=256, block_k=256),
+        [((512, 512), BF16), ((512, 512), BF16)],
+    ),
+    "paged_attention_pallas": (
+        paged_attention_pallas,
+        [((4, 16, 128), BF16), ((65, 16, 16, 128), BF16), ((65, 16, 16, 128), BF16),
+         ((4, 16), I32), ((4,), I32)],
+    ),
+    # σ scrambling of a batch of activations, as training calls it
+    "vmap_jit_scramble_blocks_pallas__": (
+        functools.partial(scramble_blocks_pallas, block_m=128, block_n=128, k=1),
+        [((2, 512, 512), BF16)],
+    ),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_NAMED_KERNELS))
+def test_kernel_keeps_the_name_the_trace_readers_match(one_chip, family):
+    fn, shapes = _NAMED_KERNELS[family]
+    hlo = _compile_hlo(fn, one_chip, *shapes)
+    kernels = {
+        op_family(line.strip().removeprefix("ROOT "))
+        for line in hlo.splitlines()
+        if "tpu_custom_call" in line
+    }
+    assert family in kernels, kernels
