@@ -7,12 +7,13 @@ Decode attention must therefore gather K/V through the block table instead
 of slicing a dense per-sequence cache.  Two implementations live behind a
 capability door mirroring the GEMM backend registry (`kernels/api.py`):
 
-  pallas_paged  one `pallas_call` whose k/v BlockSpec index_maps read the
-                scalar-prefetched block table — page `p` of sequence `s`
-                streams pool row `bt[s, p]` straight into VMEM (no gathered
-                copy of the context is ever materialized), with the flash
-                (m, l, acc) online-softmax recurrence in VMEM scratch and
-                pages past the sequence length skipped entirely.
+  pallas_paged  one `pallas_call` over the slots whose steps copy pool
+                rows `bt[s, p]` (every KV head of a page at once) from HBM
+                straight into VMEM by the scalar-prefetched block table (no
+                gathered copy of the context is ever materialized), a block
+                of whole pages at a time and only as far as the slot's
+                length reaches, with the flash (m, l, acc) online-softmax
+                recurrence in VMEM scratch.
   xla_gather    `pool[block_table]` gather + masked softmax, written
                 op-for-op like `models.attention._sdpa` so decode through
                 pages is **bitwise equal** to decode against the dense cache
@@ -32,9 +33,9 @@ Layout contract (single decode token per sequence slot):
                                      scheduler's scratch page (inactive
                                      slots write there, never read back).
                                      KV-head-major inside a page, so one
-                                     (page, kv-head) block is a full
-                                     (page_size, hd) tile and obeys the
-                                     TPU's (8, 128) block rule
+                                     page of every head is one contiguous
+                                     copy and each head's rows are whole
+                                     (page_size, hd) tiles
   block_tables  (S, n_pages) int32   page ids per slot; unallocated -> 0
   lengths       (S,) int32           valid context length INCLUDING the
                                      freshly written token (= pos + 1)
@@ -65,6 +66,7 @@ __all__ = [
     "paged_attention_pallas",
     "paged_attention_xla",
     "paged_impl_names",
+    "pages_per_block",
     "register_paged_impl",
     "resolve_paged_impl",
 ]
@@ -123,44 +125,117 @@ def paged_attention_xla(
 # Pallas kernel: block-table-steered gather attention
 # ---------------------------------------------------------------------------
 
+# Bytes of K (and as many of V) that one block of pages brings into VMEM.
+# K and V are both double-buffered: the kernel's VMEM holds four times this.
+_BLOCK_BYTES = 512 * 1024
+
+
+def pages_per_block(
+    kv_heads: int, page_size: int, head_dim: int, dtype, n_pages: int
+) -> int:
+    """Whole pages (all KV heads of each) one grid step visits at a time.
+
+    As many as fit `_BLOCK_BYTES`, at least one and at most the table's
+    `n_pages`: 8 pages of 64 KiB at 16 KV heads of 128 in bf16, 16 of 32 KiB
+    at 8 heads.
+    """
+    page_bytes = kv_heads * page_size * head_dim * jnp.dtype(dtype).itemsize
+    return max(1, min(n_pages, _BLOCK_BYTES // page_bytes))
+
 
 def _paged_kernel(
     bt_ref,  # SMEM (S, n_pages) block tables (scalar prefetch)
     len_ref,  # SMEM (S,) valid lengths (scalar prefetch)
-    q_ref,  # (rep, hd) query rows for this (slot, kv-head)
-    k_ref,  # (ps, hd) one page of keys for this kv-head
-    v_ref,  # (ps, hd) one page of values for this kv-head
-    o_ref,  # (rep, hd)
-    m_ref,  # VMEM (rep, 1) running max
-    l_ref,  # VMEM (rep, 1) running denominator
-    acc_ref,  # VMEM (rep, hd) f32 accumulator
+    q_ref,  # VMEM (KV, rep, hd) this slot's query rows
+    k_hbm,  # HBM (P, KV, ps, hd) key pool
+    v_hbm,  # HBM (P, KV, ps, hd) value pool
+    o_ref,  # VMEM (KV, rep, hd)
+    k_buf,  # VMEM (2, KV, ppb * ps, hd) double-buffered block of keys
+    v_buf,  # VMEM (2, KV, ppb * ps, hd) double-buffered block of values
+    k_sem,  # DMA (2,) one semaphore per buffer
+    v_sem,  # DMA (2,)
+    m_ref,  # VMEM (KV, rep, 1) running max
+    l_ref,  # VMEM (KV, rep, 1) running denominator
+    acc_ref,  # VMEM (KV, rep, hd) f32 accumulator
+    cur_ref,  # SMEM (1,) the buffer the next block lands in
     *,
     page_size: int,
-    n_pages: int,
+    pages_per_block: int,
     scale: float,
 ):
     s = pl.program_id(0)
-    p = pl.program_id(2)
+    n_slots = pl.num_programs(0)
+    n_pages = bt_ref.shape[1]
+    block_len = pages_per_block * page_size
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def live_len(i):
+        return jnp.minimum(len_ref[i], n_pages * page_size)
 
+    def n_blocks(i):
+        return (live_len(i) + block_len - 1) // block_len
+
+    kv = ((k_hbm, k_buf, k_sem), (v_hbm, v_buf, v_sem))
+
+    def each_page(i, b, buf, pools, op):
+        # Only the pages the slot's length reaches are copied; the rest of
+        # the buffer keeps older (finite) pages, which the mask zeroes.
+        n_live = (live_len(i) + page_size - 1) // page_size
+        for j in range(pages_per_block):
+            page = b * pages_per_block + j
+
+            @pl.when(page < n_live)
+            def _():
+                row = bt_ref[i, page]
+                dst = pl.ds(j * page_size, page_size)
+                for hbm, vmem, sem in pools:
+                    copy = pltpu.make_async_copy(
+                        hbm.at[row], vmem.at[buf, :, dst], sem.at[buf]
+                    )
+                    getattr(copy, op)()
+
+    @pl.when(s == 0)
+    def _first():
+        # Buffer rows no page has reached yet must not hold NaNs for the
+        # zero probabilities of masked positions to multiply.
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        cur_ref[0] = 0
+
+    nb = n_blocks(s)
+    prev_nb = n_blocks(jnp.maximum(s - 1, 0))
+
+    # The previous step started this slot's first block unless it had none.
+    @pl.when((nb > 0) & ((s == 0) | (prev_nb == 0)))
+    def _prime():
+        each_page(s, 0, cur_ref[0], kv, "start")
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
     length = len_ref[s]
-    start = p * page_size
+    q = q_ref[...]
 
-    # Pages entirely past the sequence length are skipped — the block table
-    # points them at the scratch page and no MXU work is issued (the paged
-    # analogue of the grouped kernel's ragged steering).
-    @pl.when(start < length)
-    def _accumulate():
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        sc = jnp.einsum("gd,td->gt", q, k, preferred_element_type=jnp.float32)
-        sc = sc * scale  # (rep, ps)
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    def block(b, carry):
+        cur = cur_ref[0]
+        nxt = 1 - cur
+
+        @pl.when(b + 1 < nb)
+        def _next_block():
+            each_page(s, b + 1, nxt, kv, "start")
+
+        @pl.when((b + 1 == nb) & (s + 1 < n_slots))
+        def _next_slot():
+            @pl.when(n_blocks(s + 1) > 0)
+            def _():
+                each_page(s + 1, 0, nxt, kv, "start")
+
+        each_page(s, b, cur, kv[:1], "wait")
+        # bf16 products are exact in f32, and are summed in f32.
+        sc = jnp.einsum(
+            "krd,ktd->krt", q, k_buf[cur], preferred_element_type=jnp.float32
+        )
+        sc = sc * scale  # (KV, rep, ppb * ps)
+        kpos = b * block_len + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
         sc = jnp.where(kpos < length, sc, _NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
@@ -168,16 +243,19 @@ def _paged_kernel(
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(prob, axis=-1, keepdims=True)
         m_ref[...] = m_new
-        pv = jnp.dot(
-            prob.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32
+        each_page(s, b, cur, kv[1:], "wait")
+        pv = jnp.einsum(
+            "krt,ktd->krd", prob.astype(v_buf.dtype), v_buf[cur],
+            preferred_element_type=jnp.float32,
         )
         acc_ref[...] = acc_ref[...] * corr + pv
+        cur_ref[0] = nxt
+        return carry
 
-    @pl.when(p == n_pages - 1)
-    def _flush():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)  # length >= 1 in practice
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, nb, block, 0)
+    l = l_ref[...]
+    l = jnp.where(l == 0.0, 1.0, l)  # a slot of length 0 reads zeros
+    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -190,9 +268,10 @@ def paged_attention_pallas(
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """One pallas_call over grid (slots, kv_heads, pages); the k/v index_maps
-    consume the scalar-prefetched block table, so page p of slot s DMAs pool
-    row bt[s, p] directly — the gather IS the block placement."""
+    """One pallas_call over grid (slots,): each step loops over the blocks
+    of whole pages its slot's length reaches, copying each page (all KV
+    heads) from the pools in HBM by the block table while the previous block
+    is computed, across the boundary into the next slot's first block."""
     if not _HAVE_PLTPU:
         raise NotImplementedError(
             "paged_attention_pallas needs jax.experimental.pallas.tpu"
@@ -211,39 +290,39 @@ def paged_attention_pallas(
         )
     rep = h // kvh
     n_pages = block_tables.shape[1]
-    scale = hd**-0.5
+    ppb = pages_per_block(kvh, ps, hd, k_pool.dtype, n_pages)
 
     qf = q.reshape(s, kvh, rep, hd)
 
     kernel = functools.partial(
-        _paged_kernel, page_size=ps, n_pages=n_pages, scale=scale
+        _paged_kernel, page_size=ps, pages_per_block=ppb, scale=hd**-0.5
     )
+    rows = pl.BlockSpec((None, kvh, rep, hd), lambda i, bt, ln: (i, 0, 0, 0))
+    buf = pltpu.VMEM((2, kvh, ppb * ps, hd), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, kvh, n_pages),
+        grid=(s,),
         in_specs=[
-            pl.BlockSpec((None, None, rep, hd), lambda i, j, p, bt, ln: (i, j, 0, 0)),
-            pl.BlockSpec(
-                (None, None, ps, hd), lambda i, j, p, bt, ln: (bt[i, p], j, 0, 0)
-            ),
-            pl.BlockSpec(
-                (None, None, ps, hd), lambda i, j, p, bt, ln: (bt[i, p], j, 0, 0)
-            ),
+            rows,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (None, None, rep, hd), lambda i, j, p, bt, ln: (i, j, 0, 0)
-        ),
+        out_specs=rows,
         scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, hd), jnp.float32),
+            buf,
+            buf,
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((kvh, rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, rep, hd), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     compiler_params = None
     if not interpret:  # pragma: no cover — TPU-only path
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
+        # Sequential: a step starts the next slot's first copies.
+        compiler_params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

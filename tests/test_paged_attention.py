@@ -89,6 +89,58 @@ def test_pallas_skips_pages_past_length(rng):
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x), atol=2e-6)
 
 
+# Heads wide enough that a block holds fewer pages than the table: f32 pages
+# of 16 x 128 are 128 KiB at 16 KV heads (4 pages a block, 10 page slots)
+# and 64 KiB at 8 (8 a block, 12 page slots), so the last block is short.
+_BLOCK_CASES = {"mha": (16, 16, 10), "gqa": (32, 8, 12)}
+
+
+@pytest.mark.parametrize("length", ["scratch", "one_block", "one_block_plus_1", "full"])
+@pytest.mark.parametrize("heads", sorted(_BLOCK_CASES))
+def test_pallas_block_loop_matches_xla(rng, heads, length):
+    """Slot lengths on each side of a block boundary, a slot on the scratch
+    page between two others (the prefetch crosses slots), pages shuffled."""
+    h, kvh, n_pages = _BLOCK_CASES[heads]
+    ps, hd, s = 16, 128, 3
+    ppb = pa.pages_per_block(kvh, ps, hd, jnp.float32, n_pages)
+    assert n_pages % ppb != 0
+    n = {
+        "scratch": 1,
+        "one_block": ppb * ps,
+        "one_block_plus_1": ppb * ps + 1,
+        "full": n_pages * ps,
+    }[length]
+    pool_pages = 1 + s * n_pages
+    q = jnp.asarray(rng.standard_normal((s, h, hd)), jnp.float32)
+    k_pool = jnp.asarray(rng.standard_normal((pool_pages, kvh, ps, hd)), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal((pool_pages, kvh, ps, hd)), jnp.float32)
+    tables = rng.permutation(np.arange(1, pool_pages)).reshape(s, n_pages)
+    tables[1] = 0  # an idle slot: length 1 on the scratch page
+    if n == 1:
+        tables[:, 1:] = 0
+    bt = jnp.asarray(tables, jnp.int32)
+    lengths = jnp.asarray([n, 1, n], jnp.int32)
+    out_x = pa.paged_attention_xla(q, k_pool, v_pool, bt, lengths)
+    out_p = pa.paged_attention_pallas(q, k_pool, v_pool, bt, lengths, interpret=True)
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x), atol=2e-6)
+
+
+def test_pages_per_block_is_a_function_of_shapes():
+    # the serving cells' shapes: mesh-paper chat (KV 16, 64 page slots) and
+    # Granite-3 8B offline (KV 8, 200 page slots), bf16 pages of 16 x 128
+    chat = pa.pages_per_block(16, 16, 128, jnp.bfloat16, 64)
+    granite = pa.pages_per_block(8, 16, 128, jnp.bfloat16, 200)
+    assert (chat, granite) == (8, 16)
+    assert pa.pages_per_block(8, 16, 128, np.dtype(jnp.bfloat16), 200) == granite
+    for kvh, n_pages, ppb in ((16, 64, chat), (8, 200, granite)):
+        page_bytes = kvh * 16 * 128 * 2
+        assert ppb * page_bytes <= pa._BLOCK_BYTES
+    # capped by the table, and never below one page however wide the page
+    assert pa.pages_per_block(16, 16, 128, jnp.bfloat16, 3) == 3
+    assert pa.pages_per_block(2, 8, 16, jnp.float32, 1) == 1
+    assert pa.pages_per_block(256, 64, 256, jnp.float32, 64) == 1
+
+
 def test_shape_validation(rng):
     q, k_pool, v_pool, bt, lengths = _setup(rng)
     with pytest.raises(ValueError, match="head_dim"):

@@ -126,16 +126,26 @@ def test_grouped_mesh_matmul(one_chip):
     _assert_kernel(hlo)
 
 
-@pytest.mark.parametrize("page_size", [8, 16])
-def test_paged_attention(one_chip, page_size):
-    # mesh-paper decode: 4 slots, 16 heads, 16 KV heads, hd 128; a pool of
-    # 65 pages in the (P, KV, page_size, hd) layout
-    slots, heads, kvh, hd, n_pages = 4, 16, 16, 128, 16
+# (slots, heads, KV heads, page size, page slots, pool pages), hd 128
+_PAGED_SHAPES = {
+    # mesh-paper decode at pages of 8 and 16: a pool of 65 pages
+    "8": (4, 16, 16, 8, 16, 65),
+    "16": (4, 16, 16, 16, 16, 65),
+    # the benchmark's serving cells: mesh-paper.chat, granite-3-8b.offline-long
+    "chat": (64, 16, 16, 16, 64, 4097),
+    "granite": (16, 32, 8, 16, 200, 3201),
+}
+
+
+@pytest.mark.parametrize("case", list(_PAGED_SHAPES))
+def test_paged_attention(one_chip, case):
+    slots, heads, kvh, page_size, n_pages, pool = _PAGED_SHAPES[case]
+    hd = 128
     hlo = _compile_hlo(
         paged_attention_pallas, one_chip,
         ((slots, heads, hd), BF16),
-        ((65, kvh, page_size, hd), BF16),
-        ((65, kvh, page_size, hd), BF16),
+        ((pool, kvh, page_size, hd), BF16),
+        ((pool, kvh, page_size, hd), BF16),
         ((slots, n_pages), I32),
         ((slots,), I32),
     )
