@@ -44,7 +44,9 @@ class _Cfg:
 
     d_model = D_MODEL
     num_experts = N_EXPERTS
+    experts_held = N_EXPERTS
     num_experts_per_tok = TOP_K
+    norm_topk_prob = True
     moe_d_ff = D_FF
     num_layers = 2
     num_shared_experts = 0

@@ -4,6 +4,7 @@ from repro.configs.base import ArchConfig, CONFIGS, SHAPES, ShapeSpec, get_confi
 
 # Registration imports — one module per assigned architecture + the paper's own.
 from repro.configs import (  # noqa: F401
+    deepseek_v2_lite,
     granite_3_8b,
     mesh_paper,
     mistral_large_123b,

@@ -1,7 +1,8 @@
 """Architecture config system.
 
-One frozen dataclass covers all 10 assigned architectures (dense / MoE / SSM /
-hybrid / enc-dec audio / VLM).  Every published config file under
+One frozen dataclass covers every registered architecture (dense / MoE /
+SSM / hybrid / enc-dec audio / VLM; latent attention and leading dense
+layers for DeepSeek-V2).  Every published config file under
 `repro/configs/` instantiates `ArchConfig` with the exact paper/HF numbers and
 registers it; `reduced()` derives the CPU-smoke variant used by per-arch tests
 (same family and code paths, tiny dims).
@@ -46,6 +47,27 @@ class ArchConfig:
     num_shared_experts: int = 0
     moe_d_ff: int = 0  # expert hidden dim (d_ff above = dense fallback/shared)
     router_aux_coef: float = 0.01
+    first_k_dense: int = 0  # leading layers with a dense SwiGLU of width d_ff
+    norm_topk_prob: bool = True  # renormalise the top-k routing weights
+    shared_expert_gate: bool = True  # sigmoid gate on the shared experts
+    # Expert parallelism's share of a layer: this chip holds the first
+    # num_experts_held of the router's num_experts and computes only their
+    # part (0 = all held).
+    num_experts_held: int = 0
+
+    # multi-head latent attention (DeepSeek-V2): K and V come from one
+    # kv_lora_rank-wide latent plus a qk_rope_head_dim-wide shared RoPE key
+    kv_lora_rank: int = 0  # > 0 selects MLA
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN RoPE scaling (rope_scaling type "yarn"; factor 0 = plain RoPE)
+    rope_scaling_factor: float = 0.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # SSM / RWKV / hybrid
     ssm_state_size: int = 0
@@ -107,6 +129,14 @@ class ArchConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts_held or self.num_experts
+
     def n_params_dense_blocks(self) -> int:
         """Rough parameter count (reported in DESIGN/EXPERIMENTS tables)."""
         d, L = self.d_model, self.num_layers
@@ -163,6 +193,14 @@ class ArchConfig:
             num_experts_per_tok=min(self.num_experts_per_tok, 2) if self.is_moe else 0,
             num_shared_experts=min(self.num_shared_experts, 1),
             moe_d_ff=64 if self.is_moe else 0,
+            num_experts_held=(
+                min(8, max(2, 8 * self.num_experts_held // self.num_experts))
+                if self.num_experts_held else 0
+            ),
+            kv_lora_rank=32 if self.is_mla else 0,
+            qk_nope_head_dim=16 if self.is_mla else 0,
+            qk_rope_head_dim=8 if self.is_mla else 0,
+            v_head_dim=16 if self.is_mla else 0,
             ssm_state_size=min(self.ssm_state_size, 16) if self.ssm_state_size else 0,
             ssm_num_heads=min(self.ssm_num_heads, 4) if self.ssm_num_heads else 0,
             shared_attn_period=2 if self.shared_attn_period else 0,

@@ -164,6 +164,7 @@ def _candidate_terms(spec, sched: str, local, bytes_moved: int, phases: int):
         desc["grouped"] = {
             "num_groups": grp.num_groups,
             "rows_per_group": grp.rows_per_group,
+            "packed": grp.packed,
             "per_group_flops": 2 * grp.rows_per_group * spec.k * spec.n,
             "dispatch_bytes": grp.rows * (spec.k * ia + spec.n * io),
         }

@@ -38,6 +38,8 @@ small ragged row batches against per-expert weight slabs.  Backends declare
 the `grouped` capability with a dedicated impl (the Pallas ragged mesh
 kernel in `kernels/grouped.py`; segment-masked einsum on xla/ref), and an
 `expert` collective schedule shards the group dim over a device mesh (EP).
+A packed GroupSpec lays the groups end to end with no padding between them
+(a ragged dot on xla; `grouped_packed` capability).
 
 The planner degrades instead of dying (DESIGN.md §11).  `plan()` resolves a
 capability-ordered **fallback chain** (`FALLBACK_ORDER`: pallas_mesh → xla →
@@ -220,14 +222,23 @@ class GroupSpec:
     (cumulative counts, (num_groups+1,)).  Rows at or beyond a group's size
     are zero on output.  Hashable and frozen: part of the plan-cache key, so
     blocks are autotuned once per logical group shape.
+
+    With `packed`, the groups instead lie one after another from row 0 —
+    group g owns rows [group_offsets[g], group_offsets[g+1]) — in a buffer
+    of `rows_per_group` rows, which then bounds the groups' total, not each
+    group's: no row is padding between groups, and rows past the last
+    group are zero on output.  Only backends with the `grouped_packed`
+    capability run it.
     """
 
     num_groups: int
     rows_per_group: int
+    packed: bool = False
 
     def __post_init__(self):
         object.__setattr__(self, "num_groups", int(self.num_groups))
         object.__setattr__(self, "rows_per_group", int(self.rows_per_group))
+        object.__setattr__(self, "packed", bool(self.packed))
         if self.num_groups <= 0 or self.rows_per_group <= 0:
             raise ValueError(
                 f"GroupSpec dims must be positive, got num_groups="
@@ -236,8 +247,8 @@ class GroupSpec:
 
     @property
     def rows(self) -> int:
-        """Total (static) token rows of the capacity layout."""
-        return self.num_groups * self.rows_per_group
+        """Total (static) token rows of the layout."""
+        return self.rows_per_group if self.packed else self.num_groups * self.rows_per_group
 
 
 # Physical mesh axes naming a partition: a single axis name, or (for the
@@ -628,6 +639,7 @@ class BackendCapabilities:
                       with a ShardSpec can lower through a ShardedPlan
     grouped           executes ragged-batch specs carrying a GroupSpec
                       (requires a `grouped_impl` at registration)
+    grouped_packed    executes them in the packed layout (GroupSpec.packed)
     """
 
     structures: FrozenSet[str] = frozenset({"general"})
@@ -638,6 +650,7 @@ class BackendCapabilities:
     autotune: bool = False
     sharding: bool = False
     grouped: bool = False
+    grouped_packed: bool = False
 
     def __post_init__(self):
         object.__setattr__(self, "structures", frozenset(self.structures))
@@ -762,6 +775,11 @@ def _check_capabilities(spec: GemmSpec, be: _Backend) -> Optional[str]:
         return (
             f"backend {be.name!r} does not support grouped (ragged-batch)"
             f" specs (no 'grouped' capability)"
+        )
+    if spec.group is not None and spec.group.packed and not caps.grouped_packed:
+        return (
+            f"backend {be.name!r} does not support the packed grouped layout"
+            f" (no 'grouped_packed' capability)"
         )
     if spec.shard is not None and not caps.sharding:
         return (
@@ -1257,6 +1275,7 @@ class Plan:
             d["grouped"] = {
                 "num_groups": grp.num_groups,
                 "rows_per_group": grp.rows_per_group,
+                "packed": grp.packed,
                 # dense per-group compute at the static capacity bound; the
                 # ragged steering skips the share past each group's size
                 "per_group_flops": 2 * grp.rows_per_group * self.spec.k * self.spec.n,
@@ -1698,12 +1717,62 @@ def _grouped_sizes(p: Plan, group_offsets: jax.Array) -> jax.Array:
     return (group_offsets[1:] - group_offsets[:-1]).astype(jnp.int32)
 
 
+def _packed_epilogue(p: Plan, z, group_offsets, bias, residual):
+    """The epilogue of a packed grouped product z (rows, N) in float32:
+    each row's group's bias, the activation, the residual, and zero past
+    the last group."""
+    grp = p.spec.group
+    r = jnp.arange(grp.rows)
+    if bias is not None:
+        g = jnp.searchsorted(group_offsets[1:], r, side="right")
+        z = z + bias[jnp.minimum(g, grp.num_groups - 1)].astype(jnp.float32)
+    if p.activation not in (None, "none"):
+        z = ACTIVATIONS[p.activation](z)
+    if residual is not None:
+        z = z + residual.astype(jnp.float32)
+    return jnp.where((r < group_offsets[-1])[:, None], z, 0.0).astype(p.out_dtype)
+
+
+def _packed_product(tokens, group_offsets, w, out_dtype) -> jax.Array:
+    """Product of a packed layout, as float32: a ragged dot on the TPU,
+    which computes each row against its own group's slab only and rounds
+    to `out_dtype` (its gradients then keep that type too, not float32);
+    elsewhere one masked product per group, accumulated in a loop (the
+    ragged dot's generic lowering would hold the tokens once per group)."""
+    sizes = group_offsets[1:] - group_offsets[:-1]
+
+    def ragged(tokens, group_offsets, w):
+        return jax.lax.ragged_dot(tokens, w, sizes, preferred_element_type=out_dtype).astype(jnp.float32)
+
+    def per_group(tokens, group_offsets, w):
+        r = jnp.arange(tokens.shape[0])[:, None]
+
+        def body(g, z):
+            inside = (group_offsets[g] <= r) & (r < group_offsets[g + 1])
+            zg = jnp.matmul(tokens, w[g], preferred_element_type=jnp.float32)
+            return z + jnp.where(inside, zg, 0.0)
+
+        z = jnp.zeros((tokens.shape[0], w.shape[-1]), jnp.float32)
+        return jax.lax.fori_loop(0, w.shape[0], body, z)
+
+    return jax.lax.platform_dependent(
+        tokens, group_offsets, w, tpu=ragged, default=per_group
+    )
+
+
 def _xla_grouped_impl(p: Plan, tokens, group_offsets, w, bias, residual):
     """Segment-masked einsum fallback: the capacity layout makes the ragged
     batch a dense (G, rpg, K) @ (G, K, N) product; the segment mask zeroes
-    rows past each group's size (identical contract to the Pallas kernel)."""
+    rows past each group's size (identical contract to the Pallas kernel).
+    The packed layout runs `_packed_product`."""
     grp = p.spec.group
     sizes = _grouped_sizes(p, group_offsets)
+    if grp.packed:
+        # A fused epilogue takes the float32 product, as the capacity
+        # layout's does; without one the product rounds once, to out_dtype.
+        acc = p.out_dtype if p.spec.epilogue.is_identity else jnp.float32
+        z = _packed_product(tokens, group_offsets, w, acc)
+        return _packed_epilogue(p, z, group_offsets, bias, residual)
     rpg = grp.rows_per_group
     tg = tokens.reshape(grp.num_groups, rpg, p.spec.k)
     z = jnp.einsum("grk,gkn->grn", tg, w, preferred_element_type=jnp.float32)
@@ -1723,6 +1792,17 @@ def _ref_grouped_impl(p: Plan, tokens, group_offsets, w, bias, residual):
     epilogue + segment-mask contract as every other grouped backend."""
     grp = p.spec.group
     sizes = _grouped_sizes(p, group_offsets)
+    if grp.packed:
+        r = jnp.arange(grp.rows)[:, None]
+        z = sum(
+            jnp.where(
+                (group_offsets[g] <= r) & (r < group_offsets[g + 1]),
+                jnp.matmul(tokens, w[g], preferred_element_type=jnp.float32),
+                0.0,
+            )
+            for g in range(grp.num_groups)
+        )
+        return _packed_epilogue(p, z, group_offsets, bias, residual)
     rpg = grp.rows_per_group
     outs = []
     for g in range(grp.num_groups):
@@ -1769,6 +1849,7 @@ register_backend(
         autotune=False,
         sharding=True,
         grouped=True,
+        grouped_packed=True,
     ),
     grouped_impl=_xla_grouped_impl,
 )
@@ -1799,6 +1880,7 @@ register_backend(
         autotune=False,
         sharding=True,
         grouped=True,
+        grouped_packed=True,
     ),
     grouped_impl=_ref_grouped_impl,
 )
@@ -2366,7 +2448,12 @@ def _resolve_grouped_sharding(
             f" {shard.axis_g!r} (size {pg}) required by schedule 'expert'"
             f" on mesh {shard.mesh_axes}"
         )
-    local_grp = GroupSpec(grp.num_groups // pg, grp.rows_per_group)
+    if grp.packed and pg > 1:
+        raise PlanValidationError(
+            "the packed grouped layout has no per-device group rows; schedule"
+            " 'expert' needs the capacity layout"
+        )
+    local_grp = GroupSpec(grp.num_groups // pg, grp.rows_per_group, grp.packed)
     local = dataclasses.replace(
         spec, m=local_grp.rows, group=local_grp, shard=None
     )

@@ -39,6 +39,17 @@ Layout contract (single decode token per sequence slot):
   block_tables  (S, n_pages) int32   page ids per slot; unallocated -> 0
   lengths       (S,) int32           valid context length INCLUDING the
                                      freshly written token (= pos + 1)
+
+Latent layout (multi-head latent attention's absorbed decode): one pool
+and no `v_pool`; each row is a latent key of width hd, one KV head serves
+every query head, and V is the first `v_width` columns of the K rows
+already copied — no second pool and no second copy.  The output is then
+(S, H, v_width).  `scale` multiplies the scores (default hd^-0.5).  A row
+is `latent_row_width` wide: DeepSeek-V2's 512-wide latent and 64-wide RoPE
+key (576) and 64 zero columns.  The TPU lays a 576-wide minor dim out in
+640 columns in any case, and the kernel's page copies may cut HBM only at
+whole 128-column tiles, so the zeros cost no memory; q carries zeros there
+too, so they add nothing to a score.
 """
 
 from __future__ import annotations
@@ -62,6 +73,7 @@ else:  # pragma: no cover
 __all__ = [
     "PAGED_FALLBACK_ORDER",
     "gather_pages",
+    "latent_row_width",
     "paged_attention",
     "paged_attention_pallas",
     "paged_attention_xla",
@@ -72,6 +84,12 @@ __all__ = [
 ]
 
 _NEG_INF = -1e30
+_LANES = 128  # the TPU's minor tile: HBM slices start and end on it
+
+
+def latent_row_width(width: int) -> int:
+    """Columns of a latent pool row holding `width` values."""
+    return -(-width // _LANES) * _LANES
 
 
 # ---------------------------------------------------------------------------
@@ -90,10 +108,12 @@ def gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
 def paged_attention_xla(
     q: jax.Array,
     k_pool: jax.Array,
-    v_pool: jax.Array,
+    v_pool: Optional[jax.Array],
     block_tables: jax.Array,
     lengths: jax.Array,
     *,
+    scale: Optional[float] = None,
+    v_width: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Gathered-context SDPA, op-for-op `models.attention._sdpa`.
@@ -102,23 +122,25 @@ def paged_attention_xla(
     IDENTICAL to the dense decode path so a sequence served through pages
     produces bit-identical logits to the legacy `generate()` loop — pool
     rows past `lengths` (scratch page, unwritten slots) mask to exp -> 0.0
-    exactly and contribute nothing.
+    exactly and contribute nothing.  The latent layout (`v_pool` None) reads
+    V as the first `v_width` columns of the gathered K.
     """
     del interpret  # native jnp: runs everywhere
     s, h, hd = q.shape
     k = gather_pages(k_pool, block_tables)
-    v = gather_pages(v_pool, block_tables)
+    v = k[..., :v_width] if v_pool is None else gather_pages(v_pool, block_tables)
     kvh = k.shape[2]
     rep = h // kvh
     q5 = q.reshape(s, 1, kvh, rep, hd)
     scores = jnp.einsum(
         "btkrd,bskd->bkrts", q5, k, preferred_element_type=jnp.float32
-    ) / (hd**0.5)
+    )
+    scores = scores / (hd**0.5) if scale is None else scores * scale
     valid = jnp.arange(k.shape[1])[None, :] < lengths[:, None]  # (S, T)
     scores = jnp.where(valid[:, None, None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkrts,bskd->btkrd", probs, v)
-    return out.reshape(s, h, hd)
+    return out.reshape(s, h, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -147,22 +169,28 @@ def _paged_kernel(
     bt_ref,  # SMEM (S, n_pages) block tables (scalar prefetch)
     len_ref,  # SMEM (S,) valid lengths (scalar prefetch)
     q_ref,  # VMEM (KV, rep, hd) this slot's query rows
-    k_hbm,  # HBM (P, KV, ps, hd) key pool
-    v_hbm,  # HBM (P, KV, ps, hd) value pool
-    o_ref,  # VMEM (KV, rep, hd)
-    k_buf,  # VMEM (2, KV, ppb * ps, hd) double-buffered block of keys
-    v_buf,  # VMEM (2, KV, ppb * ps, hd) double-buffered block of values
-    k_sem,  # DMA (2,) one semaphore per buffer
-    v_sem,  # DMA (2,)
-    m_ref,  # VMEM (KV, rep, 1) running max
-    l_ref,  # VMEM (KV, rep, 1) running denominator
-    acc_ref,  # VMEM (KV, rep, hd) f32 accumulator
-    cur_ref,  # SMEM (1,) the buffer the next block lands in
-    *,
+    *refs,
     page_size: int,
     pages_per_block: int,
     scale: float,
+    v_width: Optional[int],
 ):
+    # With a value pool (v_width None):
+    #   k_hbm, v_hbm  HBM (P, KV, ps, hd) key and value pools
+    #   o_ref         VMEM (KV, rep, hd)
+    #   k_buf, v_buf  VMEM (2, KV, ppb * ps, hd) double-buffered blocks
+    #   k_sem, v_sem  DMA (2,) one semaphore per buffer
+    # In the latent layout the v_* refs are absent, V is the first v_width
+    # columns of k_buf, and o_ref is (KV, rep, v_width).  Then:
+    #   m_ref, l_ref  VMEM (KV, rep, 1) running max and denominator
+    #   acc_ref       VMEM (KV, rep, dv) f32 accumulator
+    #   cur_ref       SMEM (1,) the buffer the next block lands in
+    if v_width is None:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem, m_ref, l_ref, acc_ref, cur_ref = refs
+        kv = ((k_hbm, k_buf, k_sem), (v_hbm, v_buf, v_sem))
+    else:
+        k_hbm, o_ref, k_buf, k_sem, m_ref, l_ref, acc_ref, cur_ref = refs
+        kv, v_buf = ((k_hbm, k_buf, k_sem),), None
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
     n_pages = bt_ref.shape[1]
@@ -173,8 +201,6 @@ def _paged_kernel(
 
     def n_blocks(i):
         return (live_len(i) + block_len - 1) // block_len
-
-    kv = ((k_hbm, k_buf, k_sem), (v_hbm, v_buf, v_sem))
 
     def each_page(i, b, buf, pools, op):
         # Only the pages the slot's length reaches are copied; the rest of
@@ -197,8 +223,8 @@ def _paged_kernel(
     def _first():
         # Buffer rows no page has reached yet must not hold NaNs for the
         # zero probabilities of masked positions to multiply.
-        k_buf[...] = jnp.zeros_like(k_buf)
-        v_buf[...] = jnp.zeros_like(v_buf)
+        for _, vmem, _ in kv:
+            vmem[...] = jnp.zeros_like(vmem)
         cur_ref[0] = 0
 
     nb = n_blocks(s)
@@ -244,8 +270,9 @@ def _paged_kernel(
         l_ref[...] = l_ref[...] * corr + jnp.sum(prob, axis=-1, keepdims=True)
         m_ref[...] = m_new
         each_page(s, b, cur, kv[1:], "wait")
+        v = v_buf[cur] if v_width is None else k_buf[cur, :, :, :v_width]
         pv = jnp.einsum(
-            "krt,ktd->krd", prob.astype(v_buf.dtype), v_buf[cur],
+            "krt,ktd->krd", prob.astype(v.dtype), v,
             preferred_element_type=jnp.float32,
         )
         acc_ref[...] = acc_ref[...] * corr + pv
@@ -258,20 +285,24 @@ def _paged_kernel(
     o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("scale", "v_width", "interpret"))
 def paged_attention_pallas(
     q: jax.Array,
     k_pool: jax.Array,
-    v_pool: jax.Array,
+    v_pool: Optional[jax.Array],
     block_tables: jax.Array,
     lengths: jax.Array,
     *,
+    scale: Optional[float] = None,
+    v_width: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """One pallas_call over grid (slots,): each step loops over the blocks
     of whole pages its slot's length reaches, copying each page (all KV
     heads) from the pools in HBM by the block table while the previous block
-    is computed, across the boundary into the next slot's first block."""
+    is computed, across the boundary into the next slot's first block.  In
+    the latent layout (`v_pool` None) only K is copied, and V is its first
+    `v_width` columns."""
     if not _HAVE_PLTPU:
         raise NotImplementedError(
             "paged_attention_pallas needs jax.experimental.pallas.tpu"
@@ -281,7 +312,10 @@ def paged_attention_pallas(
     n_pool, kvh, ps, hd2 = k_pool.shape
     if hd != hd2:
         raise ValueError(f"head_dim mismatch: q {q.shape} vs pool {k_pool.shape}")
-    if v_pool.shape != k_pool.shape:
+    if v_pool is None:
+        if not v_width or v_width > hd:
+            raise ValueError(f"latent layout needs 0 < v_width <= {hd}, got {v_width}")
+    elif v_pool.shape != k_pool.shape:
         raise ValueError(f"k/v pool mismatch: {k_pool.shape} vs {v_pool.shape}")
     if block_tables.shape[0] != s or lengths.shape != (s,):
         raise ValueError(
@@ -293,29 +327,29 @@ def paged_attention_pallas(
     ppb = pages_per_block(kvh, ps, hd, k_pool.dtype, n_pages)
 
     qf = q.reshape(s, kvh, rep, hd)
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    dv = v_width or hd
 
     kernel = functools.partial(
-        _paged_kernel, page_size=ps, pages_per_block=ppb, scale=hd**-0.5
+        _paged_kernel, page_size=ps, pages_per_block=ppb,
+        scale=hd**-0.5 if scale is None else scale, v_width=v_width,
     )
-    rows = pl.BlockSpec((None, kvh, rep, hd), lambda i, bt, ln: (i, 0, 0, 0))
+    index = lambda i, bt, ln: (i, 0, 0, 0)  # noqa: E731
     buf = pltpu.VMEM((2, kvh, ppb * ps, hd), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s,),
         in_specs=[
-            rows,
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((None, kvh, rep, hd), index),
+            *[pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
         ],
-        out_specs=rows,
+        out_specs=pl.BlockSpec((None, kvh, rep, dv), index),
         scratch_shapes=[
-            buf,
-            buf,
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            *[buf for _ in pools],
+            *[pltpu.SemaphoreType.DMA((2,)) for _ in pools],
             pltpu.VMEM((kvh, rep, 1), jnp.float32),
             pltpu.VMEM((kvh, rep, 1), jnp.float32),
-            pltpu.VMEM((kvh, rep, hd), jnp.float32),
+            pltpu.VMEM((kvh, rep, dv), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
@@ -326,11 +360,11 @@ def paged_attention_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, kvh, rep, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, kvh, rep, dv), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qf, k_pool, v_pool)
-    return out.reshape(s, h, hd)
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qf, *pools)
+    return out.reshape(s, h, dv)
 
 
 # ---------------------------------------------------------------------------
@@ -419,17 +453,20 @@ def resolve_paged_impl(
 def paged_attention(
     q: jax.Array,
     k_pool: jax.Array,
-    v_pool: jax.Array,
+    v_pool: Optional[jax.Array],
     block_tables: jax.Array,
     lengths: jax.Array,
     *,
+    scale: Optional[float] = None,
+    v_width: Optional[int] = None,
     impl: Optional[str] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Dispatch through the door; resolution happens at trace time (static)."""
     name = resolve_paged_impl(impl, interpret=interpret)
     return _PAGED_REGISTRY[name].fn(
-        q, k_pool, v_pool, block_tables, lengths, interpret=interpret
+        q, k_pool, v_pool, block_tables, lengths,
+        scale=scale, v_width=v_width, interpret=interpret,
     )
 
 

@@ -45,7 +45,17 @@ schedulable here (enc-dec or mixed state) and are rejected up front.
 The decode step is ONE jitted call at a fixed (max_slots,) shape — slot
 occupancy changes never retrace — and pools are deliberately NOT donated:
 a failed step leaves the pre-step pools intact, so a tick can be skipped
-and retried (graceful degradation is worth the copy).
+and retried (graceful degradation is worth the copy).  The pools are the
+ones the model declares (`paged_pool_specs`: K and V, or a latent pool per
+layer stack), all addressed by the same pages.
+
+MoE routing: the prefill and decode steps also return how many routed
+(token, expert) pairs landed on the experts this model holds, fetched with
+the step's tokens in the sync the step makes anyway.  It is recorded as a
+`held_pairs` attribute on `serve.prefill` and `serve.decode` and counted in
+`moe_routed_pairs_total{share="held"|"elsewhere"}`.  A decode step counts
+the slots that hold a sequence (a block table whose first page is not the
+scratch page 0), not the idle slots it computes alongside.
 """
 
 from __future__ import annotations
@@ -240,6 +250,20 @@ class ContinuousBatchingServer:
             "serve_tpot_seconds",
             "served request's mean gap between tokens after its first")
 
+        mcfg = model.cfg
+        # Routed pairs a token makes over the MoE layers (the leading dense
+        # layers route nothing).
+        self._pairs_per_token = (
+            mcfg.num_experts_per_tok * (mcfg.num_layers - mcfg.first_k_dense)
+            if mcfg.is_moe else 0
+        )
+        if self._pairs_per_token:
+            self._m_routed = _metrics.counter(
+                "moe_routed_pairs_total",
+                "routed (token, expert) pairs, by whether this server holds the expert",
+                labels=("share",),
+            )
+
         if self._paged:
             self.alloc = PageAllocator(cfg.num_pages)
             self.pools = {
@@ -271,20 +295,23 @@ class ContinuousBatchingServer:
             impl, interpret = cfg.impl, cfg.interpret
 
             def decode(params, tokens, pools, block_tables, positions):
-                logits, pools = model.paged_decode(
+                logits, pools, routed = model.paged_decode_routed(
                     params, tokens, pools, block_tables, positions, ctx,
                     impl=impl, interpret=interpret,
                 )
                 nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-                return nxt, pools
+                live = block_tables[:, 0] > 0  # idle slots read scratch page 0
+                routed = {k: jnp.sum(jnp.where(live, v, 0)) for k, v in routed.items()}
+                return nxt, pools, routed
 
             # NOT donated: a failed/skipped tick must leave pools intact.
             self._decode = jax.jit(decode)
 
             def scatter(pools, caches, pages):
-                # caches: {"k","v"} (L, 1, T, KV, hd); pages: (n,) ids.
-                # T is padded up to n*page_size; the zero tail is masked by
-                # `lengths` in attention and overwritten as decode proceeds.
+                # caches: the prefill's {name: (L, 1, T, KV, hd)}, one per
+                # pool; pages: (n,) ids.  T is padded up to n*page_size; the
+                # zero tail is masked by `lengths` in attention and
+                # overwritten as decode proceeds.
                 def put(pool, c):
                     layers, _, t, kvh, hd = c.shape
                     n = pages.shape[0]
@@ -293,10 +320,7 @@ class ContinuousBatchingServer:
                     c2 = c2.reshape(layers, n, ps, kvh, hd).transpose(0, 1, 3, 2, 4)
                     return pool.at[:, pages].set(c2.astype(pool.dtype))
 
-                return {
-                    "k": put(pools["k"], caches["k"]),
-                    "v": put(pools["v"], caches["v"]),
-                }
+                return {name: put(pool, caches[name]) for name, pool in pools.items()}
 
             self._scatter = jax.jit(scatter)  # one trace per (T, n) pair
         else:
@@ -305,7 +329,7 @@ class ContinuousBatchingServer:
                 # rwkv decode is position-free; state rows are per-slot.
                 logits, state = model.decode(params, tokens, state, jnp.int32(0), ctx)
                 nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-                return nxt, state
+                return nxt, state, {}
 
             self._decode = jax.jit(decode_ssm)
 
@@ -341,6 +365,15 @@ class ContinuousBatchingServer:
         if self._pages_for(total) > self.cfg.num_pages - 1:
             return "too_long:pool"
         return None
+
+    def _count_routed(self, span, routed, tokens: int) -> None:
+        """Record a step's routing counts, already on the host."""
+        if not routed:
+            return
+        held = int(np.sum(routed["held_pairs"]))
+        span.set("held_pairs", held)
+        self._m_routed.inc(held, share="held")
+        self._m_routed.inc(tokens * self._pairs_per_token - held, share="elsewhere")
 
     # -- lifecycle events ----------------------------------------------------
 
@@ -489,8 +522,8 @@ class ContinuousBatchingServer:
             slot = self._free_slots.pop()
             with _obs.span("serve.admit", rid=req.rid):
                 t_admit = time.monotonic()
-                with _obs.span("serve.prefill", rid=req.rid, tokens=prefill_len):
-                    first_tok, state = self._run_prefill(req)
+                with _obs.span("serve.prefill", rid=req.rid, tokens=prefill_len) as sp_prefill:
+                    first_tok, state, routed = self._run_prefill(req)
                 self._m_admitted.inc()
                 with _obs.span("serve.scatter", rid=req.rid):
                     if self._paged:
@@ -504,8 +537,10 @@ class ContinuousBatchingServer:
                 # The prefill emits the first token greedily; it is on the
                 # host once this sync returns.
                 with _obs.span("serve.first_sync", rid=req.rid):
-                    tok = int(first_tok[0])
+                    tok, routed = jax.device_get((first_tok[0], routed))
+                    tok = int(tok)
                 t_first = time.monotonic()
+                self._count_routed(sp_prefill, routed, prefill_len)
             self._m_ttft.observe(t_first - submitted_at)
             _obs.record(
                 "serve.first_token", submitted_at, t_first, rid=req.rid,
@@ -614,21 +649,22 @@ class ContinuousBatchingServer:
         if not ready:
             return
         # The decode span covers the upload, the jitted step AND the host
-        # sync (np.asarray blocks): the tick's decode wall time.
-        with _obs.span("serve.decode", slots=len(ready), tick=self._tick):
+        # sync (device_get blocks): the tick's decode wall time.
+        with _obs.span("serve.decode", slots=len(ready), tick=self._tick) as sp:
             with _obs.span("serve.upload"):
                 tokens = jnp.asarray(tokens)
                 if self._paged:
                     tables = jnp.asarray(tables)
                     positions = jnp.asarray(positions)
             if self._paged:
-                nxt, self.pools = self._decode(
+                nxt, self.pools, routed = self._decode(
                     self.params, tokens, self.pools, tables, positions
                 )
             else:
-                nxt, self.state = self._decode(self.params, tokens, self.state)
+                nxt, self.state, routed = self._decode(self.params, tokens, self.state)
             with _obs.span("serve.decode_sync"):
-                nxt = np.asarray(nxt)
+                nxt, routed = jax.device_get((nxt, routed))
+            self._count_routed(sp, routed, len(ready))
         now = time.monotonic()
         self.counters["decode_tokens"] += len(ready)
         self._m_tokens.inc(len(ready))

@@ -218,7 +218,7 @@ def generate(
     batch = {"tokens": prompts, "labels": prompts}
     if cfg.family == "vlm":
         batch["patches"] = jnp.zeros((b, cfg.num_stub_patches, cfg.d_model), cfg.adtype)
-    next_tok, state = prefill(params, batch)
+    next_tok, state, _ = prefill(params, batch)
     # Grow caches to prompt+gen capacity where the family uses KV caches:
     # prefill returns length-T caches; decode writes at position `pos`, so we
     # pad the cache length dim up front (recurrent families carry O(1) state).

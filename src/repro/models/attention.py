@@ -1,9 +1,21 @@
-"""GQA attention with RoPE, KV cache, cross-attention, and TP/SP sharding.
+"""GQA attention with RoPE, KV cache, cross-attention, and TP/SP sharding;
+multi-head latent attention (MLA, DeepSeek-V2).
 
 Modes:
   * full causal (train / prefill — prefill also writes the cache),
   * single-token decode against a cache (serve_step),
   * bidirectional (whisper encoder), cross-attention (whisper decoder).
+
+MLA caches one latent row a token, `[rmsnorm(c_kv) | rope(k_pe) | 0]`
+(kv_lora_rank + qk_rope_head_dim values, zero-padded to whole 128-column
+tiles for the paged kernel, `latent_row_width`; one "head"), in place of K
+and V.
+Train and prefill run the published form: K and V are expanded from the
+latent per head, q.k is qk_nope + qk_rope wide and v is v_head_dim wide.
+Decode runs the absorbed form: q_nope is taken into the latent through
+W_UK^T, scores are q_abs . [c_kv | k_pe] over the cached rows, and the
+attention-weighted latent goes out through W_UV, then wo — the same
+mathematics, without expanding K or V for the whole cache.
 
 Sharding: activations ('batch','seq','heads','head_dim'); the KV cache uses
 ('kv_batch','kv_seq','kv_heads','head_dim') so long-context decode can switch
@@ -20,13 +32,27 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import PSpec, ShardCtx, apply_rope, dense
+from repro.kernels.paged_attention import latent_row_width
+from repro.models.layers import (
+    PSpec,
+    ShardCtx,
+    apply_rope,
+    dense,
+    rmsnorm,
+    rope_inv_freq,
+    yarn_mscale,
+)
 
 __all__ = [
     "attn_specs",
     "attention",
     "attention_paged_decode",
+    "cache_leaves",
     "init_cache_shape",
+    "mla_attention",
+    "mla_paged_decode",
+    "mla_scale",
+    "mla_specs",
     "Cache",
 ]
 
@@ -50,9 +76,17 @@ def attn_specs(cfg, *, prefix_scale: float = 1.0) -> Dict[str, PSpec]:
     return specs
 
 
-def init_cache_shape(cfg, batch: int, max_len: int) -> Dict[str, Tuple[int, ...]]:
+def cache_leaves(cfg) -> Dict[str, Tuple[int, int]]:
+    """(heads, width) of each per-layer cache leaf: K and V, or MLA's one
+    latent row a token."""
+    if cfg.is_mla:
+        return {"latent": (1, latent_row_width(cfg.kv_lora_rank + cfg.qk_rope_head_dim))}
     kv, hd = cfg.num_kv_heads, cfg.head_dim_
-    return {"k": (batch, max_len, kv, hd), "v": (batch, max_len, kv, hd)}
+    return {"k": (kv, hd), "v": (kv, hd)}
+
+
+def init_cache_shape(cfg, batch: int, max_len: int) -> Dict[str, Tuple[int, ...]]:
+    return {name: (batch, max_len, h, w) for name, (h, w) in cache_leaves(cfg).items()}
 
 
 def _sdpa_chunked(
@@ -125,15 +159,18 @@ def _sdpa(
     causal: bool,
     q_offset: jax.Array | int = 0,
     kv_valid_len: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Grouped-query SDPA with f32 softmax; no KV-head materialized repeat."""
+    """Grouped-query SDPA with f32 softmax; no KV-head materialized repeat.
+    V may be narrower than q and K (MLA); `scale` defaults to hd^-0.5."""
     b, tq, h, hd = q.shape
     tk, kvh = k.shape[1], k.shape[2]
     rep = h // kvh
     q5 = q.reshape(b, tq, kvh, rep, hd)
     scores = jnp.einsum(
         "btkrd,bskd->bkrts", q5, k, preferred_element_type=jnp.float32
-    ) / (hd**0.5)
+    )
+    scores = scores / (hd**0.5) if scale is None else scores * scale
     if causal:
         qpos = jnp.arange(tq)[:, None] + q_offset  # (Tq, 1)
         kpos = jnp.arange(tk)[None, :]
@@ -144,7 +181,7 @@ def _sdpa(
         scores = jnp.where(valid[:, None, None, None] if valid.ndim == 2 else valid[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkrts,bskd->btkrd", probs, v)
-    return out.reshape(b, tq, h, hd)
+    return out.reshape(b, tq, h, v.shape[-1])
 
 
 def attention_paged_decode(
@@ -291,3 +328,175 @@ def attention(
     out = ctx.c(out, ("batch", "seq", "heads", "head_dim"))
     y = dense(out.reshape(b, t, h * hd), p["wo"], cfg, ctx=ctx)
     return ctx.c(y, ("batch", "seq", "embed")), new_cache
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, no query compression)
+# ---------------------------------------------------------------------------
+
+
+def mla_specs(cfg) -> Dict[str, PSpec]:
+    """wq: per head [q_nope | q_pe]; wkv_a: [c_kv | k_pe]; kv_norm on c_kv;
+    wkv_b: the latent to per head [k_nope | v] (W_UK, W_UV); wo."""
+    d, h = cfg.d_model, cfg.num_heads
+    r, nope, rope, dv = (
+        cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    )
+    out_scale = 0.02 / max(1.0, (2 * cfg.num_layers) ** 0.5)
+    return {
+        "wq": PSpec((d, h * (nope + rope)), ("embed", "heads"), 0.02),
+        "wkv_a": PSpec((d, r + rope), ("embed", None), 0.02),
+        "kv_norm": PSpec((r,), (None,), init="ones"),
+        "wkv_b": PSpec((r, h * (nope + dv)), (None, "heads"), 0.02),
+        "wo": PSpec((h * dv, d), ("heads", "embed"), out_scale),
+    }
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale: (qk_nope + qk_rope)^-0.5, times YaRN's mscale(factor,
+    mscale_all_dim) squared when the context is stretched."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_scaling_factor and cfg.rope_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_scaling_factor, cfg.rope_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_project(p, x, cfg, ctx, positions):
+    """(q_nope (B,T,H,nope), q_pe (B,T,H,rope), latent rows (B,T,1,W)):
+    the RoPE parts rotated by YaRN's frequencies, pair (2i, 2i+1) by
+    frequency i, and scaled by YaRN's cos/sin factor when it is not 1; a
+    row is [c_kv | k_pe] and zeros to `latent_row_width`."""
+    b, t, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = dense(x, p["wq"], cfg, ctx=ctx).reshape(b, t, h, nope + rope)
+    ckv = dense(x, p["wkv_a"], cfg, ctx=ctx)
+    c = rmsnorm(ckv[..., :r], p["kv_norm"], cfg.norm_eps)
+    inv = rope_inv_freq(cfg, rope)
+    q_pe = apply_rope(q[..., nope:], positions, cfg.rope_theta, inv)
+    k_pe = apply_rope(ckv[..., None, r:], positions, cfg.rope_theta, inv)
+    f = cfg.rope_scaling_factor
+    if f:
+        m = yarn_mscale(f, cfg.rope_mscale) / yarn_mscale(f, cfg.rope_mscale_all_dim)
+        if m != 1.0:
+            q_pe, k_pe = q_pe * m, k_pe * m
+    return q[..., :nope], q_pe, _pad_latent(jnp.concatenate([c[:, :, None], k_pe], axis=-1))
+
+
+def _pad_latent(x):
+    """Zero columns up to the latent row width."""
+    width = x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, latent_row_width(width) - width)])
+
+
+def _mla_up(p, cfg):
+    """W_UK (r, H, nope) and W_UV (r, H, dv) from wkv_b."""
+    w = p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads, -1)
+    return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
+
+
+def _mla_absorbed(p, q_nope, q_pe, attend, cfg, ctx):
+    """Absorbed attention: q_abs = [q_nope W_UK^T | q_pe | 0] (the latent
+    row's width), `attend(q_abs)` -> the attention-weighted latent
+    (B,T,H,r), then W_UV and wo."""
+    b, t, h, _ = q_nope.shape
+    w_uk, w_uv = _mla_up(p, cfg)
+    q_lat = jnp.einsum(
+        "bthn,rhn->bthr", q_nope, w_uk.astype(q_nope.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(q_nope.dtype)
+    q_abs = _pad_latent(jnp.concatenate([q_lat, q_pe], axis=-1))
+    q_abs = ctx.c(q_abs, ("batch", "seq", "heads", None))
+    o_lat = attend(q_abs)
+    out = jnp.einsum(
+        "bthr,rhv->bthv", o_lat, w_uv.astype(o_lat.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(q_nope.dtype)
+    out = ctx.c(out, ("batch", "seq", "heads", "head_dim"))
+    y = dense(out.reshape(b, t, h * cfg.v_head_dim), p["wo"], cfg, ctx=ctx)
+    return ctx.c(y, ("batch", "seq", "embed"))
+
+
+def mla_attention(
+    p: Dict[str, jax.Array],
+    x: jax.Array,  # (B, T, D)
+    cfg,
+    ctx: ShardCtx,
+    *,
+    cache: Optional[Cache] = None,
+    cache_pos: Optional[jax.Array] = None,
+    write_cache: bool = False,
+) -> Tuple[jax.Array, Optional[Cache]]:
+    """`attention`'s causal modes for MLA; the cache is {"latent": (B, T, 1,
+    W)}, the rows of `_mla_project`.  Without a cache the published form (K/V expanded from the
+    latent); against one, the absorbed form over the cached rows."""
+    b, t, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    pos0 = cache_pos if cache_pos is not None else 0
+    positions = jnp.broadcast_to(jnp.arange(t)[None, :] + pos0, (b, t))
+    q_nope, q_pe, row = _mla_project(p, x, cfg, ctx, positions)
+    scale = mla_scale(cfg)
+
+    if cache is not None:
+        lat = jax.lax.dynamic_update_slice(
+            cache["latent"], row.astype(cache["latent"].dtype), (0, cache_pos, 0, 0)
+        )
+        lat = ctx.c(lat, ("kv_batch", "kv_seq", "kv_heads", None))
+
+        def attend(q_abs):
+            return _sdpa(q_abs, lat, lat[..., :r], causal=True, q_offset=cache_pos,
+                         kv_valid_len=cache_pos + t, scale=scale)
+
+        return _mla_absorbed(p, q_nope, q_pe, attend, cfg, ctx), {"latent": lat}
+
+    kv = dense(row[:, :, 0, :r], p["wkv_b"], cfg, ctx=ctx).reshape(b, t, h, nope + dv)
+    rope = cfg.qk_rope_head_dim
+    k_pe = jnp.broadcast_to(row[:, :, :, r : r + rope], (b, t, h, rope))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    q = ctx.c(q, ("batch", "seq", "heads", "head_dim"))
+    k = ctx.c(k, ("batch", "seq", "heads", "head_dim"))
+    out = _sdpa(q, k, kv[..., nope:], causal=True, scale=scale)
+    out = ctx.c(out, ("batch", "seq", "heads", "head_dim"))
+    y = dense(out.reshape(b, t, h * dv), p["wo"], cfg, ctx=ctx)
+    new_cache = {"latent": ctx.c(row, ("kv_batch", "kv_seq", "kv_heads", None))} if write_cache else None
+    return ctx.c(y, ("batch", "seq", "embed")), new_cache
+
+
+def mla_paged_decode(
+    p: Dict[str, jax.Array],
+    x: jax.Array,  # (S, 1, D)
+    cfg,
+    ctx: ShardCtx,
+    *,
+    pool: jax.Array,  # (P, 1, page_size, W) latent page pool
+    block_tables: jax.Array,
+    positions: jax.Array,
+    impl: Optional[str] = None,
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """`attention_paged_decode` for MLA: the slot's latent row lands in its
+    page, then the absorbed form attends over the latent pages through
+    `kernels.paged_attention`'s latent layout (V = the rows' first r
+    columns).  Returns (y (S, 1, D), pool with the row written)."""
+    from repro.kernels.paged_attention import paged_attention
+
+    s, t, _ = x.shape
+    if t != 1:
+        raise ValueError(f"paged decode is single-token; got T={t}")
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    q_nope, q_pe, row = _mla_project(p, x, cfg, ctx, positions[:, None])
+
+    ps = pool.shape[2]
+    page = jnp.take_along_axis(block_tables, (positions // ps)[:, None], axis=1)[:, 0]
+    pool = pool.at[page, :, positions % ps].set(row[:, 0].astype(pool.dtype))
+
+    def attend(q_abs):
+        out = paged_attention(
+            q_abs.reshape(s, h, -1), pool, None, block_tables, positions + 1,
+            scale=mla_scale(cfg), v_width=r, impl=impl, interpret=interpret,
+        )
+        return out.reshape(s, 1, h, r)
+
+    return _mla_absorbed(p, q_nope, q_pe, attend, cfg, ctx), pool

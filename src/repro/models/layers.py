@@ -38,6 +38,8 @@ __all__ = [
     "rmsnorm",
     "RotaryTable",
     "apply_rope",
+    "rope_inv_freq",
+    "yarn_mscale",
     "softmax_xent",
     "gemm",
     "grouped_gemm",
@@ -208,6 +210,7 @@ def grouped_gemm(
     out_dtype=None,
     mesh: Any = None,
     shard: Any = None,
+    packed: bool = False,
 ) -> jax.Array:
     """Config-routed grouped (ragged-batch) GEMM via plan/execute.
 
@@ -218,7 +221,9 @@ def grouped_gemm(
     cached per logical group shape exactly like `gemm` — one autotune, one
     executable, every layer/step reuses it.  With `shard` (a ShardSpec
     carrying axis_g) and the live `mesh`, the plan lowers through the
-    `expert` collective schedule (EP).
+    `expert` collective schedule (EP).  With `packed` the groups lie end to
+    end in `tokens` (`GroupSpec.packed`: group g owns rows
+    [group_offsets[g], group_offsets[g+1])); only the XLA path runs it.
     """
     backend = "pallas_mesh" if getattr(cfg, "use_mesh_kernel", False) else "xla"
     num_groups, kd, n = weights.shape
@@ -229,7 +234,7 @@ def grouped_gemm(
         getattr(cfg, "mesh_block_k", 0) or None,
     )
     spec = _api.GemmSpec.for_groups(
-        _api.GroupSpec(num_groups, rows // num_groups),
+        _api.GroupSpec(num_groups, rows if packed else rows // num_groups, packed),
         k=kd,
         n=n,
         dtype_a=tokens.dtype,
@@ -280,10 +285,45 @@ class RotaryTable:
         return positions[..., None].astype(jnp.float32) * self.inv_freq
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, T, H, hd); positions: (B, T) or (T,).  Rotate pairs (even, odd)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction 0.1 * mscale * ln(factor) + 1 (1 when the
+    context is not stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(cfg, dim: int) -> np.ndarray:
+    """Inverse frequencies of the dim/2 rotated pairs: theta^(-2i/dim), or
+    under YaRN (cfg.rope_scaling_factor) those of the fast-turning pairs
+    kept, those of the slow ones divided by the factor, and a linear ramp
+    between the pairs that turn beta_fast and beta_slow times over the
+    original context (Peng et al. 2023, as DeepSeek-V2 runs it)."""
+    theta = cfg.rope_theta
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    factor = cfg.rope_scaling_factor
+    if not factor:
+        return extra
+
+    def pair(rotations):
+        ctx = cfg.rope_original_max_position
+        return dim * math.log(ctx / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo = max(math.floor(pair(cfg.rope_beta_fast)), 0)
+    hi = min(math.ceil(pair(cfg.rope_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope(
+    x: jax.Array, positions: jax.Array, theta: float, inv_freq: Optional[np.ndarray] = None
+) -> jax.Array:
+    """x: (B, T, H, hd); positions: (B, T) or (T,).  Rotate pairs (even, odd),
+    pair i by positions * inv_freq[i] (default theta^(-2i/hd))."""
     hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    if inv_freq is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * inv  # (B, T, hd/2)

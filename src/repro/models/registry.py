@@ -4,7 +4,10 @@
   init / abstract_params / logical_axes      parameter trees (1 source: PSpec)
   forward(params, batch, ctx)                train/eval logits
   loss(params, batch, ctx)                   scalar loss + metrics
-  prefill / decode + decode_state_specs      serving path
+  prefill / decode + decode_state_specs      serving path (prefill_routed
+                                             and paged_decode_routed also
+                                             return the MoE routing counts,
+                                             {} when the model has no experts)
   batch_specs(shape) / decode_input_specs    ShapeDtypeStructs + logical axes
                                              for dry-run lowering (no alloc)
 
@@ -76,6 +79,10 @@ class Model:
         return loss, metrics
 
     def prefill(self, params, batch, ctx: ShardCtx = ShardCtx()):
+        return self.prefill_routed(params, batch, ctx)[:2]
+
+    def prefill_routed(self, params, batch, ctx: ShardCtx = ShardCtx()):
+        """`prefill`'s (logits, decode state), then the routing counts."""
         return self._prefill(params, batch, self.cfg, ctx)
 
     def decode(self, params, tokens, state, pos, ctx: ShardCtx = ShardCtx()):
@@ -93,7 +100,7 @@ class Model:
         self,
         params,
         tokens,  # (S, 1)
-        pools,  # {"k","v"}: (L, P, KV, page_size, hd)
+        pools,  # paged_pool_specs: {name: (L, P, KV, page_size, hd)}
         block_tables,  # (S, n_pages)
         positions,  # (S,)
         ctx: ShardCtx = ShardCtx(),
@@ -101,7 +108,26 @@ class Model:
         impl: Optional[str] = None,
         interpret: bool = False,
     ):
-        """One continuous-batching decode step against paged KV pools."""
+        """One continuous-batching decode step against paged KV pools:
+        (logits, pools)."""
+        return self.paged_decode_routed(
+            params, tokens, pools, block_tables, positions, ctx,
+            impl=impl, interpret=interpret,
+        )[:2]
+
+    def paged_decode_routed(
+        self,
+        params,
+        tokens,
+        pools,
+        block_tables,
+        positions,
+        ctx: ShardCtx = ShardCtx(),
+        *,
+        impl: Optional[str] = None,
+        interpret: bool = False,
+    ):
+        """`paged_decode`'s (logits, pools), then the routing counts."""
         if self._paged_decode is None:
             raise NotImplementedError(
                 f"family {self.cfg.family!r} has no paged decode path"
@@ -212,6 +238,16 @@ def _rwkv_forward(params, batch, cfg, ctx):
     return rwkv.rwkv_forward(params, batch["tokens"], cfg, ctx)
 
 
+def _no_experts(prefill: Callable) -> Callable:
+    """A family's prefill, with the empty routing counts of a model that
+    has no experts."""
+
+    def run(params, batch, cfg, ctx):
+        return (*prefill(params, batch, cfg, ctx), {})
+
+    return run
+
+
 def _rwkv_prefill(params, batch, cfg, ctx):
     return rwkv.rwkv_prefill(params, batch["tokens"], cfg, ctx)
 
@@ -241,7 +277,7 @@ def get_model(cfg: ArchConfig) -> Model:
             cfg,
             rwkv.rwkv_specs,
             _rwkv_forward,
-            _rwkv_prefill,
+            _no_experts(_rwkv_prefill),
             rwkv.rwkv_decode,
             lambda c, b, m: rwkv.rwkv_state_specs(c, b),
         )
@@ -250,7 +286,7 @@ def get_model(cfg: ArchConfig) -> Model:
             cfg,
             ssm.zamba_specs,
             _zamba_forward,
-            _zamba_prefill,
+            _no_experts(_zamba_prefill),
             ssm.zamba_decode,
             ssm.zamba_state_specs,
         )
@@ -259,7 +295,7 @@ def get_model(cfg: ArchConfig) -> Model:
             cfg,
             whisper.whisper_specs,
             whisper.whisper_forward,
-            whisper.whisper_prefill,
+            _no_experts(whisper.whisper_prefill),
             whisper.whisper_decode,
             lambda c, b, m: whisper.whisper_cache_specs(c, b, m, m // c.dec_ratio),
         )
@@ -268,7 +304,7 @@ def get_model(cfg: ArchConfig) -> Model:
             cfg,
             vlm.vlm_specs,
             vlm.vlm_forward,
-            vlm.vlm_prefill,
+            _no_experts(vlm.vlm_prefill),
             vlm.vlm_decode,
             lambda c, b, m: vlm.vlm_cache_specs(c, b, m + c.num_stub_patches),
             # vlm decode is structurally lm_decode (patches only affect

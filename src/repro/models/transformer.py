@@ -1,13 +1,20 @@
-"""Decoder-only transformer LM (dense + MoE families).
+"""Decoder-only transformer LM (the dense and MoE families).
 
-Covers granite-3-8b, phi3-medium-14b, qwen2-7b, mistral-large-123b (dense),
-olmoe-1b-7b, qwen2-moe-a2.7b (MoE), the pixtral-12b backbone, and the
-mesh-paper demo config.
+Every registered config of family dense or moe runs here, and the
+pixtral-12b backbone: GQA or multi-head latent attention (cfg.kv_lora_rank,
+DeepSeek-V2), a SwiGLU or MoE feed-forward, and leading dense layers before
+the MoE ones (cfg.first_k_dense).
 
-Layers are stacked on a leading (L,) axis and executed with `jax.lax.scan`
-(compile time ~independent of depth — essential for 88-layer dry-runs) with a
-configurable remat policy.  Entry points: `lm_forward` (train), `lm_prefill`,
-`lm_decode` (serving, stacked per-layer KV caches carried through the scan).
+Layers of one kind are stacked on a leading (L,) axis and executed with
+`jax.lax.scan` (compile time ~independent of depth — essential for 88-layer
+dry-runs) with a configurable remat policy.  A model has one such stack,
+`blocks`, or, with leading dense layers, `dense_blocks` then `blocks`, each
+scanned in turn (`layer_stacks`).  Entry points: `lm_forward` (train),
+`lm_prefill`, `lm_decode` and `lm_decode_paged` (serving: per-layer caches,
+stacked per stack and carried through its scan; a dense_blocks stack's
+cache leaves are named `dense_<leaf>`).  The serving entry points return
+the MoE layers' routing counts of each sequence beside the caches ({} for
+a dense model).
 
 The paper's scrambling system is integrated as an optional privacy transform:
 with cfg.scramble_privacy the embedding-output activation block-grid is
@@ -30,7 +37,11 @@ from repro.models.attention import (
     attention,
     attention_paged_decode,
     attn_specs,
+    cache_leaves,
     init_cache_shape,
+    mla_attention,
+    mla_paged_decode,
+    mla_specs,
 )
 from repro.models.layers import PSpec, ShardCtx, gemm, padded_vocab, rmsnorm
 from repro.models.moe import moe_block, moe_specs, swiglu, swiglu_specs
@@ -47,6 +58,7 @@ __all__ = [
     "unembed",
     "block_specs",
     "block_apply",
+    "layer_stacks",
 ]
 
 
@@ -61,18 +73,39 @@ def stack_specs(specs: Any, num: int) -> Any:
     )
 
 
-def block_specs(cfg) -> Dict[str, Any]:
+def layer_stacks(cfg) -> Tuple[Tuple[str, bool, int], ...]:
+    """(params key, MoE feed-forward?, layers) of each scanned stack, in
+    order: the leading dense layers of an MoE model, then the rest."""
+    k = cfg.first_k_dense if cfg.is_moe else 0
+    rest = ("blocks", cfg.is_moe, cfg.num_layers - k)
+    return (("dense_blocks", False, k), rest) if k else (rest,)
+
+
+def _cache_prefix(stack: str) -> str:
+    return stack[: -len("blocks")]
+
+
+def block_specs(cfg, moe: Optional[bool] = None) -> Dict[str, Any]:
     """One transformer block: attn + (SwiGLU | MoE) + 2 norms."""
     specs: Dict[str, Any] = {
         "ln1": PSpec((cfg.d_model,), ("embed",), init="ones"),
         "ln2": PSpec((cfg.d_model,), ("embed",), init="ones"),
-        "attn": attn_specs(cfg),
+        "attn": mla_specs(cfg) if cfg.is_mla else attn_specs(cfg),
     }
-    if cfg.is_moe:
+    if cfg.is_moe if moe is None else moe:
         specs["moe"] = moe_specs(cfg)
     else:
         specs["mlp"] = swiglu_specs(cfg, cfg.d_ff)
     return specs
+
+
+def _ffn(p, x, cfg, ctx):
+    """The block's feed-forward on its normed input: MoE where the block
+    holds experts, else SwiGLU.  Returns (output, aux)."""
+    y = rmsnorm(x, p["ln2"], cfg.norm_eps)
+    if "moe" in p:
+        return moe_block(p["moe"], y, cfg, ctx)
+    return swiglu(p["mlp"], y, cfg, ctx), {}
 
 
 def block_apply(
@@ -86,7 +119,7 @@ def block_apply(
     write_cache: bool = False,
 ) -> Tuple[jax.Array, Any, Dict[str, jax.Array]]:
     """Pre-norm block.  Returns (x, new_cache, aux)."""
-    h, new_cache = attention(
+    h, new_cache = (mla_attention if cfg.is_mla else attention)(
         p["attn"],
         rmsnorm(x, p["ln1"], cfg.norm_eps),
         cfg,
@@ -96,11 +129,7 @@ def block_apply(
         write_cache=write_cache,
     )
     x = x + h
-    aux = {}
-    if cfg.is_moe:
-        h2, aux = moe_block(p["moe"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, ctx)
-    else:
-        h2 = swiglu(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, ctx)
+    h2, aux = _ffn(p, x, cfg, ctx)
     return x + h2, new_cache, aux
 
 
@@ -108,7 +137,10 @@ def lm_specs(cfg) -> Dict[str, Any]:
     vpad = padded_vocab(cfg)
     specs: Dict[str, Any] = {
         "embed": PSpec((vpad, cfg.d_model), ("vocab", "embed"), 0.02),
-        "blocks": stack_specs(block_specs(cfg), cfg.num_layers),
+        **{
+            name: stack_specs(block_specs(cfg, moe), n)
+            for name, moe, n in layer_stacks(cfg)
+        },
         "final_norm": PSpec((cfg.d_model,), ("embed",), init="ones"),
     }
     if not cfg.tie_embeddings:
@@ -183,24 +215,43 @@ def lm_forward(params, tokens: jax.Array, cfg, ctx: ShardCtx = ShardCtx()):
         return y, aux_vec
 
     body = _remat(body, cfg.remat_policy)
-    x, aux_stack = jax.lax.scan(body, x, params["blocks"], unroll=cfg.scan_unroll)
+    # The routing losses are the last stack's: the MoE layers follow any
+    # leading dense ones.
+    for name, _, _ in layer_stacks(cfg):
+        x, aux_stack = jax.lax.scan(body, x, params[name], unroll=cfg.scan_unroll)
     x = _maybe_scramble(x, cfg, ctx, inverse=True)
     logits = unembed(params, x, cfg, ctx)
     aux = {"lb_loss": aux_stack[:, 0].mean(), "router_z": aux_stack[:, 1].mean()}
     return logits, aux
 
 
+def _routed(per_stack) -> Dict[str, jax.Array]:
+    """The MoE layers' routing counts of a step per sequence (B,), summed
+    over layers and positions ({} for a model without experts)."""
+    held = [aux["held_pairs"] for aux in per_stack if "held_pairs" in aux]
+    return {"held_pairs": sum(jnp.sum(h, axis=(0, 2)) for h in held)} if held else {}
+
+
+def _counts(aux) -> Dict[str, jax.Array]:
+    return {"held_pairs": aux["held_pairs"]} if "held_pairs" in aux else {}
+
+
 def lm_prefill(params, tokens: jax.Array, cfg, ctx: ShardCtx = ShardCtx()):
-    """Prefill: returns (logits (B, T, V), stacked caches (L, B, T, KV, hd))."""
+    """Prefill: returns (logits (B, T, V), caches {name: (L, B, T, KV, hd)},
+    routing counts)."""
     x = embed_tokens(params, tokens, cfg, ctx)
 
     def body(x, lp):
-        y, cache, _ = block_apply(lp, x, cfg, ctx, write_cache=True)
-        return ctx.c(y, ("batch", "seq_sp", "embed")), cache
+        y, cache, aux = block_apply(lp, x, cfg, ctx, write_cache=True)
+        return ctx.c(y, ("batch", "seq_sp", "embed")), (cache, _counts(aux))
 
-    x, caches = jax.lax.scan(body, x, params["blocks"], unroll=cfg.scan_unroll)
+    caches, per_stack = {}, []
+    for name, _, _ in layer_stacks(cfg):
+        x, (stacked, aux) = jax.lax.scan(body, x, params[name], unroll=cfg.scan_unroll)
+        caches.update({_cache_prefix(name) + k: v for k, v in stacked.items()})
+        per_stack.append(aux)
     logits = unembed(params, x, cfg, ctx)
-    return logits, caches
+    return logits, caches, _routed(per_stack)
 
 
 def lm_decode(
@@ -219,7 +270,12 @@ def lm_decode(
         y, new_cache, _ = block_apply(lp, x, cfg, ctx, cache=cache, cache_pos=pos)
         return y, new_cache
 
-    x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches), unroll=cfg.scan_unroll)
+    new_caches = {}
+    for name, _, _ in layer_stacks(cfg):
+        pre = _cache_prefix(name)
+        mine = {leaf: caches[pre + leaf] for leaf in cache_leaves(cfg)}
+        x, new = jax.lax.scan(body, x, (params[name], mine), unroll=cfg.scan_unroll)
+        new_caches.update({pre + k: v for k, v in new.items()})
     logits = unembed(params, x, cfg, ctx)
     return logits, new_caches
 
@@ -230,38 +286,34 @@ def block_apply_paged(
     cfg,
     ctx: ShardCtx,
     *,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
+    pools: Dict[str, jax.Array],  # this layer's {"k","v"} or {"latent"}
     block_tables: jax.Array,
     positions: jax.Array,
     impl: Optional[str] = None,
     interpret: bool = False,
-) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """`block_apply`'s decode branch against a paged KV pool (DESIGN.md §12)."""
-    h, pools = attention_paged_decode(
-        p["attn"],
-        rmsnorm(x, p["ln1"], cfg.norm_eps),
-        cfg,
-        ctx,
-        k_pool=k_pool,
-        v_pool=v_pool,
-        block_tables=block_tables,
-        positions=positions,
-        impl=impl,
-        interpret=interpret,
-    )
-    x = x + h
-    if cfg.is_moe:
-        h2, _ = moe_block(p["moe"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, ctx)
+) -> Tuple[jax.Array, Dict[str, jax.Array], Dict[str, jax.Array]]:
+    """`block_apply`'s decode branch against a paged pool (DESIGN.md §12).
+    Returns (x, pools, aux)."""
+    y = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    paged = dict(block_tables=block_tables, positions=positions, impl=impl,
+                 interpret=interpret)
+    if cfg.is_mla:
+        h, lat = mla_paged_decode(p["attn"], y, cfg, ctx, pool=pools["latent"], **paged)
+        pools = {"latent": lat}
     else:
-        h2 = swiglu(p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, ctx)
-    return x + h2, pools
+        h, (k, v) = attention_paged_decode(
+            p["attn"], y, cfg, ctx, k_pool=pools["k"], v_pool=pools["v"], **paged
+        )
+        pools = {"k": k, "v": v}
+    x = x + h
+    h2, aux = _ffn(p, x, cfg, ctx)
+    return x + h2, pools, aux
 
 
 def lm_decode_paged(
     params,
     tokens: jax.Array,  # (S, 1) — one token per sequence slot
-    pools,  # {"k","v"}: (L, P, KV, page_size, hd) shared page pools
+    pools,  # paged_pool_specs: {name: (L, P, KV, page_size, hd)}
     block_tables: jax.Array,  # (S, n_pages) int32
     positions: jax.Array,  # (S,) int32 per-slot lengths
     cfg,
@@ -272,39 +324,45 @@ def lm_decode_paged(
 ):
     """One continuous-batching decode step: every slot advances one token
     against its own block-table pages (per-slot positions — slots sit at
-    different depths).  Returns (logits (S, 1, V), updated pools)."""
+    different depths).  Returns (logits (S, 1, V), updated pools, routing
+    counts)."""
     x = embed_tokens(params, tokens, cfg, ctx)
 
     def body(x, layer_in):
-        lp, kp, vp = layer_in
-        y, (nk, nv) = block_apply_paged(
+        lp, layer_pools = layer_in
+        y, new, aux = block_apply_paged(
             lp,
             x,
             cfg,
             ctx,
-            k_pool=kp,
-            v_pool=vp,
+            pools=layer_pools,
             block_tables=block_tables,
             positions=positions,
             impl=impl,
             interpret=interpret,
         )
-        return y, (nk, nv)
+        return y, (new, _counts(aux))
 
-    x, (ks, vs) = jax.lax.scan(
-        body, x, (params["blocks"], pools["k"], pools["v"]), unroll=cfg.scan_unroll
-    )
+    new_pools, per_stack = {}, []
+    for name, _, _ in layer_stacks(cfg):
+        pre = _cache_prefix(name)
+        mine = {leaf: pools[pre + leaf] for leaf in cache_leaves(cfg)}
+        x, (new, aux) = jax.lax.scan(body, x, (params[name], mine), unroll=cfg.scan_unroll)
+        new_pools.update({pre + k: v for k, v in new.items()})
+        per_stack.append(aux)
     logits = unembed(params, x, cfg, ctx)
-    return logits, {"k": ks, "v": vs}
+    return logits, new_pools, _routed(per_stack)
 
 
 def paged_pool_specs(cfg, num_pages: int, page_size: int):
-    """Abstract stacked page pools for the serving scheduler (one per layer)."""
-    kv, hd = cfg.num_kv_heads, cfg.head_dim_
-    shp = (cfg.num_layers, num_pages, kv, page_size, hd)
+    """Abstract stacked page pools for the serving scheduler: per stack and
+    cache leaf, (layers, num_pages, heads, page_size, width)."""
     return {
-        "k": jax.ShapeDtypeStruct(shp, cfg.adtype),
-        "v": jax.ShapeDtypeStruct(shp, cfg.adtype),
+        _cache_prefix(name) + leaf: jax.ShapeDtypeStruct(
+            (n, num_pages, heads, page_size, width), cfg.adtype
+        )
+        for name, _, n in layer_stacks(cfg)
+        for leaf, (heads, width) in cache_leaves(cfg).items()
     }
 
 
@@ -312,6 +370,7 @@ def decode_cache_specs(cfg, batch: int, max_len: int):
     """Abstract stacked cache for serve_step lowering (ShapeDtypeStruct tree)."""
     shp = init_cache_shape(cfg, batch, max_len)
     return {
-        name: jax.ShapeDtypeStruct((cfg.num_layers,) + s, cfg.adtype)
+        _cache_prefix(stack) + name: jax.ShapeDtypeStruct((n,) + s, cfg.adtype)
+        for stack, _, n in layer_stacks(cfg)
         for name, s in shp.items()
     }

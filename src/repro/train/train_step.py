@@ -108,10 +108,10 @@ def make_train_step(
 
 def make_prefill_step(model: Model, ctx: ShardCtx = ShardCtx()) -> Callable:
     def prefill_step(params, batch):
-        logits, state = model.prefill(params, batch, ctx)
+        logits, state, routed = model.prefill_routed(params, batch, ctx)
         # next-token from the last position — the serving handoff artifact
         next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return next_tok, state
+        return next_tok, state, routed
 
     return prefill_step
 

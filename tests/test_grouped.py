@@ -300,6 +300,72 @@ def test_roofline_understands_grouped_plans():
 
 
 # ---------------------------------------------------------------------------
+# The packed layout: groups end to end, no padding between them
+# ---------------------------------------------------------------------------
+
+
+def _packed_case(sizes=(5, 0, 9, 3), rows=24, k=24, n=20, seed=6):
+    rng = np.random.default_rng(seed)
+    # rows past the last group hold garbage: the contract zeroes them
+    tokens = jnp.asarray(rng.normal(size=(rows, k)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(len(sizes), k, n)).astype(np.float32))
+    off = jnp.asarray(np.concatenate([[0], np.cumsum(sizes)]), jnp.int32)
+    return tokens, off, w
+
+
+@pytest.mark.parametrize("backend", ["xla", "ref"])
+def test_grouped_packed_plan_matches_a_loop_over_groups(backend):
+    """Row r of group g is epilogue(tokens[r] @ w[g] + bias[g]); rows past
+    the last group are zero, empty groups take no rows."""
+    tokens, off, w = _packed_case()
+    rng = np.random.default_rng(7)
+    bias = jnp.asarray(rng.normal(size=(4, 20)).astype(np.float32))
+    res = jnp.asarray(rng.normal(size=(24, 20)).astype(np.float32))
+    spec = api.GemmSpec.for_groups(
+        api.GroupSpec(4, 24, packed=True), 24, 20,
+        epilogue=api.Epilogue(bias=True, activation="gelu", residual=True),
+    )
+    assert spec.m == 24
+    out = api.plan(spec, backend=backend)(tokens, off, w, bias=bias, residual=res)
+    want = np.zeros((24, 20), np.float32)
+    o = np.asarray(off)
+    for g in range(4):
+        z = tokens[o[g]:o[g + 1]] @ w[g] + bias[g]
+        want[o[g]:o[g + 1]] = np.asarray(api.ACTIVATIONS["gelu"](z) + res[o[g]:o[g + 1]])
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-5)
+    assert not np.any(np.asarray(out)[o[-1]:])
+
+
+def test_grouped_packed_grads_match_the_capacity_layout():
+    """The packed layout's gradients are the capacity layout's, row for row."""
+    sizes = [5, 0, 9, 3]
+    tokens, off, w = _packed_case(sizes)
+    o = np.asarray(off)
+    cap = jnp.zeros((4 * 16, 24), jnp.float32)
+    for g in range(4):
+        cap = cap.at[16 * g:16 * g + sizes[g]].set(tokens[o[g]:o[g + 1]])
+    packed = api.plan(api.GemmSpec.for_groups(api.GroupSpec(4, 24, packed=True), 24, 20),
+                      backend="xla")
+    capacity = api.plan(api.GemmSpec.for_groups(api.GroupSpec(4, 16), 24, 20), backend="xla")
+    gp = jax.grad(lambda t, ww: jnp.sum(packed(t, off, ww) ** 2), argnums=(0, 1))(tokens, w)
+    gc = jax.grad(lambda t, ww: jnp.sum(capacity(t, off, ww) ** 2), argnums=(0, 1))(cap, w)
+    np.testing.assert_allclose(np.asarray(gp[1]), np.asarray(gc[1]), rtol=1e-5, atol=1e-4)
+    for g in range(4):
+        np.testing.assert_allclose(np.asarray(gp[0][o[g]:o[g + 1]]),
+                                   np.asarray(gc[0][16 * g:16 * g + sizes[g]]),
+                                   rtol=1e-5, atol=1e-4)
+    assert not np.any(np.asarray(gp[0])[o[-1]:])
+
+
+def test_grouped_packed_is_refused_without_its_capability():
+    spec = api.GemmSpec.for_groups(api.GroupSpec(4, 24, packed=True), 24, 20)
+    with pytest.raises(api.CapabilityError, match="packed"):
+        api.plan(spec, backend="pallas_mesh")
+    assert api.GroupSpec(4, 24, packed=True).rows == 24
+    assert api.GroupSpec(4, 24).rows == 96
+
+
+# ---------------------------------------------------------------------------
 # MoE on the grouped planner
 # ---------------------------------------------------------------------------
 
@@ -341,6 +407,29 @@ def test_moe_block_one_grouped_plan_per_expert_shape():
     assert len(grouped) == 2  # wi: d -> 2f, wo: f -> d
     shapes = {p["mkn"] for p in grouped}
     assert len(shapes) == 2
+
+
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "qwen2-moe-a2.7b"])
+@pytest.mark.parametrize("seq", [40, 160])
+def test_moe_block_packed_layout_matches_the_capacity_layout(arch, seq):
+    """Above 256 tokens XLA's packed buffer holds n * min(k, held) rows, at
+    fewer its capacity layout held * n, as the mesh kernel's always does:
+    the same pairs, the same outputs."""
+    cfg = get_config(arch).reduced()
+    params = init_params(jax.random.PRNGKey(0), moe_specs(cfg), cfg.pdtype)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, cfg.d_model), cfg.adtype)
+    y_packed, aux_packed = moe_block(params, x, cfg, NO_SHARD)
+    n = 2 * seq
+    grouped = [p for p in api.plan_cache_info()["plans"] if p.get("grouped")]
+    assert {p["grouped"]["packed"] for p in grouped} == {n > 256}
+    rows = {int(p["mkn"].split("x")[0]) for p in grouped}
+    bound = n * min(cfg.num_experts_per_tok, cfg.experts_held) if n > 256 else n * cfg.experts_held
+    assert rows == {bound}
+    mesh_cfg = dataclasses.replace(cfg, use_mesh_kernel=True)
+    y_cap, aux_cap = moe_block(params, x, mesh_cfg, NO_SHARD)
+    np.testing.assert_allclose(np.asarray(y_packed), np.asarray(y_cap), rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(aux_packed["held_pairs"]),
+                                  np.asarray(aux_cap["held_pairs"]))
 
 
 def test_moe_block_grouped_trains():

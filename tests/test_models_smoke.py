@@ -1,4 +1,5 @@
-"""Per-architecture smoke tests (all 10 assigned archs + paper demo config).
+"""Per-architecture smoke tests (all 10 assigned archs, the paper demo
+config and DeepSeek-V2-Lite's latent attention and leading dense layer).
 
 Each arch instantiates its REDUCED config (same family/code paths, tiny dims)
 and runs:
@@ -22,7 +23,7 @@ from repro.models import get_model
 from repro.optim import AdamWConfig, constant
 from repro.train.train_step import init_train_state, make_train_step
 
-ALL_ARCHS = list(ASSIGNED_ARCHS) + ["mesh-paper"]
+ALL_ARCHS = list(ASSIGNED_ARCHS) + ["mesh-paper", "deepseek-v2-lite"]
 
 
 def _batch_for(cfg, b=2, t=16, seed=0):

@@ -57,6 +57,39 @@ def test_gqa_head_ratios(rng, h, kvh):
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x), atol=2e-6)
 
 
+@pytest.mark.parametrize("ps,lengths", [(8, [5, 17, 32]), (16, [1, 40, 64])])
+def test_latent_layout_reads_v_from_k(rng, ps, lengths):
+    """One latent pool, no value pool: V is the first v_width columns of
+    the K rows.  The gather impl equals `_sdpa` over the gathered rows with
+    that V, and the kernel (interpreted) equals the gather."""
+    s, h, width, v_width, scale = 3, 16, 128, 32, 0.3
+    n_pages = 4
+    pool_pages = 1 + s * n_pages
+    q = jnp.asarray(rng.standard_normal((s, h, width)), jnp.float32)
+    pool = jnp.asarray(rng.standard_normal((pool_pages, 1, ps, width)), jnp.float32)
+    bt = jnp.asarray(rng.permutation(np.arange(1, pool_pages)).reshape(s, n_pages), jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    out_x = pa.paged_attention_xla(q, pool, None, bt, lengths, scale=scale, v_width=v_width)
+    k = pa.gather_pages(pool, bt)
+    ref = _sdpa(q[:, None], k, k[..., :v_width], causal=False,
+                kv_valid_len=lengths[:, None], scale=scale)
+    assert out_x.shape == (s, h, v_width)
+    assert bool(jnp.all(out_x == ref[:, 0]))
+    out_p = pa.paged_attention_pallas(q, pool, None, bt, lengths, scale=scale,
+                                      v_width=v_width, interpret=True)
+    # Scores sum 128 products here (16 in the K/V cases): the kernel's
+    # blockwise online softmax reorders float32 sums of 8x as many terms.
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x), atol=1e-5)
+
+
+def test_latent_layout_needs_a_value_width(rng):
+    q = jnp.zeros((2, 4, 128), jnp.float32)
+    pool = jnp.zeros((5, 1, 8, 128), jnp.float32)
+    bt, lengths = jnp.ones((2, 2), jnp.int32), jnp.ones((2,), jnp.int32)
+    with pytest.raises(ValueError, match="v_width"):
+        pa.paged_attention_pallas(q, pool, None, bt, lengths, interpret=True)
+
+
 def test_length_masking_ignores_tail_and_unused_pages(rng):
     """Poisoning every pool row past `lengths` (and page 0) must not change
     the output — the paged masking never reads them."""

@@ -126,6 +126,25 @@ def test_grouped_mesh_matmul(one_chip):
     _assert_kernel(hlo)
 
 
+def test_packed_grouped_plan_is_the_chips_ragged_dot(one_chip):
+    """The XLA path's packed expert buffer at DeepSeek-V2-Lite's widths (8
+    held experts, 2048 -> 2816; a 3,072-token prefill's 18,432 rows) lowers
+    to the chip's ragged dot, not to the per-group loop that other
+    platforms run."""
+    from repro.kernels import api
+
+    spec = api.GemmSpec.for_groups(
+        api.GroupSpec(8, 18432, packed=True), 2048, 2816,
+        dtype_a=BF16, dtype_b=BF16, out_dtype=BF16,
+    )
+    plan = api.plan(spec, backend="xla")
+    hlo = _compile_hlo(
+        lambda t, off, w: plan(t, off, w), one_chip,
+        ((18432, 2048), BF16), ((9,), I32), ((8, 2048, 2816), BF16),
+    )
+    assert "ragged" in hlo.lower()
+
+
 # (slots, heads, KV heads, page size, page slots, pool pages), hd 128
 _PAGED_SHAPES = {
     # mesh-paper decode at pages of 8 and 16: a pool of 65 pages
@@ -148,6 +167,20 @@ def test_paged_attention(one_chip, case):
         ((pool, kvh, page_size, hd), BF16),
         ((slots, n_pages), I32),
         ((slots,), I32),
+    )
+    _assert_kernel(hlo)
+
+
+def test_paged_attention_latent(one_chip):
+    """deepseek-v2-lite.decode-long: 32 slots, 16 heads on one latent row of
+    640 columns (576 values), V its first 512, 224 pages a slot."""
+    fn = functools.partial(paged_attention_pallas, scale=0.1, v_width=512)
+    hlo = _compile_hlo(
+        lambda q, pool, bt, ln: fn(q, pool, None, bt, ln), one_chip,
+        ((32, 16, 640), BF16),
+        ((7169, 1, 16, 640), BF16),
+        ((32, 224), I32),
+        ((32,), I32),
     )
     _assert_kernel(hlo)
 
