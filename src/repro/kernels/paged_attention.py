@@ -29,8 +29,10 @@ door resolves to `xla_gather`; on TPU it resolves to `pallas_paged`.
 Layout contract (single decode token per sequence slot):
 
   q             (S, H, hd)           one query token per slot
-  k_pool/v_pool (P, KV, page_size, hd)  shared pools; page 0 is the
-                                     scheduler's scratch page (inactive
+  k_pool/v_pool (P, KV, page_size, hd)  shared pools (the decode step
+                                     passes a layer stack's pools as one
+                                     of L*P pages); a layer's page 0 is
+                                     the scheduler's scratch page (inactive
                                      slots write there, never read back).
                                      KV-head-major inside a page, so one
                                      page of every head is one contiguous

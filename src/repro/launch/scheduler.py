@@ -43,11 +43,17 @@ deadline / shedding ladder, no pages to allocate.  hybrid / audio are not
 schedulable here (enc-dec or mixed state) and are rejected up front.
 
 The decode step is ONE jitted call at a fixed (max_slots,) shape — slot
-occupancy changes never retrace — and pools are deliberately NOT donated:
-a failed step leaves the pre-step pools intact, so a tick can be skipped
-and retried (graceful degradation is worth the copy).  The pools are the
-ones the model declares (`paged_pool_specs`: K and V, or a latent pool per
-layer stack), all addressed by the same pages.
+occupancy changes never retrace.  The pools are the ones the model
+declares (`paged_pool_specs`: K and V, or a latent pool per layer stack),
+all addressed by the same pages, and the decode step and the admission
+scatter DONATE them: each updates the pools in place, writing one row a
+slot and layer (or the prompt's pages), and the server keeps the buffers
+it returns.  `serve_pool_in_place_total{step="decode"|"scatter"}` counts
+the calls after which the pools handed in read deleted.  A `serve.step`
+fault fires before the step runs, so a skipped tick keeps its pools.  An
+exception raised inside a jitted step propagates as before, but the pools
+it was handed are gone with it: the server cannot decode again and must
+be rebuilt (no recovery path holds a second copy of the pools).
 
 MoE routing: the prefill and decode steps also return how many routed
 (token, expert) pairs landed on the experts this model holds, fetched with
@@ -249,6 +255,11 @@ class ContinuousBatchingServer:
         self._m_tpot = _metrics.histogram(
             "serve_tpot_seconds",
             "served request's mean gap between tokens after its first")
+        self._m_in_place = _metrics.counter(
+            "serve_pool_in_place_total",
+            "steps after which the KV pools handed in were consumed in place",
+            labels=("step",),
+        )
 
         mcfg = model.cfg
         # Routed pairs a token makes over the MoE layers (the leading dense
@@ -304,8 +315,9 @@ class ContinuousBatchingServer:
                 routed = {k: jnp.sum(jnp.where(live, v, 0)) for k, v in routed.items()}
                 return nxt, pools, routed
 
-            # NOT donated: a failed/skipped tick must leave pools intact.
-            self._decode = jax.jit(decode)
+            # Donated: the step writes one row a slot and layer into the
+            # pools in place (a failure inside it loses them: module doc).
+            self._decode = jax.jit(decode, donate_argnums=(2,))
 
             def scatter(pools, caches, pages):
                 # caches: the prefill's {name: (L, 1, T, KV, hd)}, one per
@@ -322,7 +334,8 @@ class ContinuousBatchingServer:
 
                 return {name: put(pool, caches[name]) for name, pool in pools.items()}
 
-            self._scatter = jax.jit(scatter)  # one trace per (T, n) pair
+            # One trace per (T, n) pair; pools donated, as for decode.
+            self._scatter = jax.jit(scatter, donate_argnums=(0,))
         else:
 
             def decode_ssm(params, tokens, state):
@@ -527,9 +540,9 @@ class ContinuousBatchingServer:
                 self._m_admitted.inc()
                 with _obs.span("serve.scatter", rid=req.rid):
                     if self._paged:
-                        self.pools = self._scatter(
+                        self._keep_pools("scatter", self._scatter(
                             self.pools, state, jnp.asarray(pages, jnp.int32)
-                        )
+                        ))
                     else:
                         self.state = self._insert_state(
                             self.state, state, jnp.int32(slot)
@@ -643,6 +656,13 @@ class ContinuousBatchingServer:
                 tables[seq.slot, : len(seq.pages)] = seq.pages
         return ready, tokens, positions, tables
 
+    def _keep_pools(self, step: str, pools) -> None:
+        """Hold the pools a donating step returned; count the step as in
+        place when the buffers it was handed read deleted."""
+        if all(p.is_deleted() for p in self.pools.values()):
+            self._m_in_place.inc(step=step)
+        self.pools = pools
+
     def _decode_tick(self) -> None:
         with _obs.span("serve.inputs"):
             ready, tokens, positions, tables = self.decode_inputs()
@@ -657,9 +677,10 @@ class ContinuousBatchingServer:
                     tables = jnp.asarray(tables)
                     positions = jnp.asarray(positions)
             if self._paged:
-                nxt, self.pools, routed = self._decode(
+                nxt, pools, routed = self._decode(
                     self.params, tokens, self.pools, tables, positions
                 )
+                self._keep_pools("decode", pools)
             else:
                 nxt, self.state, routed = self._decode(self.params, tokens, self.state)
             with _obs.span("serve.decode_sync"):
@@ -728,10 +749,11 @@ class ContinuousBatchingServer:
         tokens = jnp.zeros((s_max, 1), jnp.int32)
         positions = jnp.zeros((s_max,), jnp.int32)
         if self._paged:
-            # All-zero tables: the trace writes only the scratch page; the
-            # updated pools are discarded.
+            # All-zero tables: the step writes only the scratch pages.  It
+            # consumes the pools it is given, so the server keeps the ones
+            # it returns.
             tables = jnp.zeros((s_max, self.cfg.max_pages_per_seq), jnp.int32)
-            self._decode(self.params, tokens, self.pools, tables, positions)
+            _, self.pools, _ = self._decode(self.params, tokens, self.pools, tables, positions)
         else:
             self._decode(self.params, tokens, self.state)
 

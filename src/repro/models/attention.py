@@ -184,33 +184,75 @@ def _sdpa(
     return out.reshape(b, tq, h, v.shape[-1])
 
 
+def _write_slot_rows(
+    pools: Dict[str, jax.Array],  # {name: (L, P, KV, page_size, hd)}
+    rows: Dict[str, jax.Array],  # {name: (S, KV, hd)} one new row a slot
+    layer: jax.Array,  # scalar int32
+    block_tables: jax.Array,  # (S, n_pages) int32
+    positions: jax.Array,  # (S,) int32
+) -> Dict[str, jax.Array]:
+    """Each slot's new row of each pool, written in place at `[layer,
+    block_tables[s, pos // page_size], :, pos % page_size]`.
+
+    Each slot's page is read, the row put in at its offset, and the S pages
+    written back whole: one gather and one scatter a pool, whose window is
+    the page's own (page_size, hd) tiles, so XLA keeps the pool's layout
+    and updates it in place.  A scatter of the rows alone, or a
+    dynamic-update-slice a row, makes XLA on the TPU lay the pool out anew
+    and copy it whole around the write.  Idle slots (all-zero table,
+    position 0) all write layer `layer`'s scratch page 0; which of them
+    lands there is of no account."""
+    ps = next(iter(pools.values())).shape[3]
+    page = jnp.take_along_axis(block_tables, (positions // ps)[:, None], axis=1)[:, 0]
+    at_row = jnp.arange(ps)[None, None, :, None] == (positions % ps)[:, None, None, None]
+
+    def put(pool, row):
+        cur = pool[layer, page]  # (S, KV, ps, hd): each slot's page
+        new = jnp.where(at_row, row.astype(pool.dtype)[:, :, None, :], cur)
+        return pool.at[layer, page].set(new)
+
+    return {name: put(pool, rows[name]) for name, pool in pools.items()}
+
+
+def _layer_pages(pool: jax.Array, layer: jax.Array, block_tables: jax.Array):
+    """A stacked pool (L, P, KV, ps, hd) as one pool of L*P pages, and
+    `block_tables` moved to layer `layer`'s pages.  Merging the two leading
+    dims is a view (no copy); the kernel and the gather read the layer's
+    pages through the shifted tables, and an idle slot's zeros land on the
+    layer's own scratch page."""
+    n_layers, n_pages = pool.shape[:2]
+    return pool.reshape(n_layers * n_pages, *pool.shape[2:]), block_tables + layer * n_pages
+
+
 def attention_paged_decode(
     p: Dict[str, jax.Array],
     x: jax.Array,  # (S, 1, D) — one new token per sequence slot
     cfg,
     ctx: ShardCtx,
     *,
-    k_pool: jax.Array,  # (P, KV, page_size, hd) shared page pool
-    v_pool: jax.Array,
+    pools: Dict[str, jax.Array],  # {"k", "v"}: (L, P, KV, page_size, hd) stacked pools
+    layer: jax.Array,  # scalar int32: this layer's index in the stack
     block_tables: jax.Array,  # (S, n_pages) int32
     positions: jax.Array,  # (S,) int32 — each slot's current length
     impl: Optional[str] = None,
     interpret: bool = False,
-) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Single-token decode against a paged KV pool (DESIGN.md §12).
 
     The per-slot analogue of the `cache=...` branch of `attention`: the new
-    K/V lands in page `block_tables[s, pos // page_size]` at in-page offset
-    `pos % page_size`, then the slot attends over its pages through
-    `kernels.paged_attention`.  Per-slot positions replace the shared scalar
-    `cache_pos`, so every slot can sit at a different depth — the property
-    continuous batching needs.  Op-for-op identical per row to the dense
-    decode path (the xla_gather impl mirrors `_sdpa`), so a request served
-    through pages is bitwise-equal to `generate()`.
+    K/V lands in page `block_tables[s, pos // page_size]` of layer `layer`
+    at in-page offset `pos % page_size` (`_write_slot_rows`), then the slot
+    attends over its pages through `kernels.paged_attention`.  Per-slot
+    positions replace the shared scalar `cache_pos`, so every slot can sit
+    at a different depth — the property continuous batching needs.
+    Op-for-op identical per row to the dense decode path (the xla_gather
+    impl mirrors `_sdpa`), so a request served through pages is
+    bitwise-equal to `generate()`.
 
-    Inactive slots (all-zero block table, position 0) write into page 0 —
-    the scheduler's reserved scratch page — and their output is discarded.
-    Returns (y (S, 1, D), (k_pool, v_pool) with the token written).
+    Inactive slots (all-zero block table, position 0) write into their
+    layer's page 0 — the scheduler's reserved scratch page — and their
+    output is discarded.  Returns (y (S, 1, D), the stacked pools with the
+    token written).
     """
     from repro.kernels.paged_attention import paged_attention
 
@@ -227,26 +269,23 @@ def attention_paged_decode(
     k = apply_rope(k, pos2, cfg.rope_theta)
     q = ctx.c(q, ("batch", "seq", "heads", "head_dim"))
 
-    ps = k_pool.shape[2]
-    page = jnp.take_along_axis(block_tables, (positions // ps)[:, None], axis=1)[:, 0]
-    off = positions % ps
-    # (S,) page ids and (S,) in-page offsets around the kv-head slice: each
-    # slot writes its (KV, hd) row into page[s][:, off[s]].
-    k_pool = k_pool.at[page, :, off].set(k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[page, :, off].set(v[:, 0].astype(v_pool.dtype))
+    pools = _write_slot_rows(pools, {"k": k[:, 0], "v": v[:, 0]}, layer,
+                            block_tables, positions)
+    k_pages, tables = _layer_pages(pools["k"], layer, block_tables)
+    v_pages, _ = _layer_pages(pools["v"], layer, block_tables)
 
     out = paged_attention(
         q.reshape(s, h, hd),
-        k_pool,
-        v_pool,
-        block_tables,
+        k_pages,
+        v_pages,
+        tables,
         positions + 1,  # valid length includes the token just written
         impl=impl,
         interpret=interpret,
     ).reshape(s, 1, h, hd)
     out = ctx.c(out, ("batch", "seq", "heads", "head_dim"))
     y = dense(out.reshape(s, 1, h * hd), p["wo"], cfg, ctx=ctx)
-    return ctx.c(y, ("batch", "seq", "embed")), (k_pool, v_pool)
+    return ctx.c(y, ("batch", "seq", "embed")), pools
 
 
 def attention(
@@ -470,16 +509,18 @@ def mla_paged_decode(
     cfg,
     ctx: ShardCtx,
     *,
-    pool: jax.Array,  # (P, 1, page_size, W) latent page pool
+    pools: Dict[str, jax.Array],  # {"latent": (L, P, 1, page_size, W)}
+    layer: jax.Array,
     block_tables: jax.Array,
     positions: jax.Array,
     impl: Optional[str] = None,
     interpret: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """`attention_paged_decode` for MLA: the slot's latent row lands in its
     page, then the absorbed form attends over the latent pages through
     `kernels.paged_attention`'s latent layout (V = the rows' first r
-    columns).  Returns (y (S, 1, D), pool with the row written)."""
+    columns).  Returns (y (S, 1, D), the stacked pool with the row
+    written)."""
     from repro.kernels.paged_attention import paged_attention
 
     s, t, _ = x.shape
@@ -488,15 +529,14 @@ def mla_paged_decode(
     h, r = cfg.num_heads, cfg.kv_lora_rank
     q_nope, q_pe, row = _mla_project(p, x, cfg, ctx, positions[:, None])
 
-    ps = pool.shape[2]
-    page = jnp.take_along_axis(block_tables, (positions // ps)[:, None], axis=1)[:, 0]
-    pool = pool.at[page, :, positions % ps].set(row[:, 0].astype(pool.dtype))
+    pools = _write_slot_rows(pools, {"latent": row[:, 0]}, layer, block_tables, positions)
+    pages, tables = _layer_pages(pools["latent"], layer, block_tables)
 
     def attend(q_abs):
         out = paged_attention(
-            q_abs.reshape(s, h, -1), pool, None, block_tables, positions + 1,
+            q_abs.reshape(s, h, -1), pages, None, tables, positions + 1,
             scale=mla_scale(cfg), v_width=r, impl=impl, interpret=interpret,
         )
         return out.reshape(s, 1, h, r)
 
-    return _mla_absorbed(p, q_nope, q_pe, attend, cfg, ctx), pool
+    return _mla_absorbed(p, q_nope, q_pe, attend, cfg, ctx), pools

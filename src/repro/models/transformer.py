@@ -286,25 +286,20 @@ def block_apply_paged(
     cfg,
     ctx: ShardCtx,
     *,
-    pools: Dict[str, jax.Array],  # this layer's {"k","v"} or {"latent"}
+    pools: Dict[str, jax.Array],  # the stack's {"k","v"} or {"latent"}, (L, P, ...)
+    layer: jax.Array,  # scalar int32: this block's index in the stack
     block_tables: jax.Array,
     positions: jax.Array,
     impl: Optional[str] = None,
     interpret: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], Dict[str, jax.Array]]:
-    """`block_apply`'s decode branch against a paged pool (DESIGN.md §12).
-    Returns (x, pools, aux)."""
+    """`block_apply`'s decode branch against the stack's paged pools
+    (DESIGN.md §12).  Returns (x, pools, aux)."""
     y = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    paged = dict(block_tables=block_tables, positions=positions, impl=impl,
-                 interpret=interpret)
-    if cfg.is_mla:
-        h, lat = mla_paged_decode(p["attn"], y, cfg, ctx, pool=pools["latent"], **paged)
-        pools = {"latent": lat}
-    else:
-        h, (k, v) = attention_paged_decode(
-            p["attn"], y, cfg, ctx, k_pool=pools["k"], v_pool=pools["v"], **paged
-        )
-        pools = {"k": k, "v": v}
+    h, pools = (mla_paged_decode if cfg.is_mla else attention_paged_decode)(
+        p["attn"], y, cfg, ctx, pools=pools, layer=layer, block_tables=block_tables,
+        positions=positions, impl=impl, interpret=interpret,
+    )
     x = x + h
     h2, aux = _ffn(p, x, cfg, ctx)
     return x + h2, pools, aux
@@ -325,30 +320,41 @@ def lm_decode_paged(
     """One continuous-batching decode step: every slot advances one token
     against its own block-table pages (per-slot positions — slots sit at
     different depths).  Returns (logits (S, 1, V), updated pools, routing
-    counts)."""
+    counts).
+
+    Each stack's pools ride the layer scan's carry whole, beside x; the
+    scan's inputs are the layer's weights and index.  A layer writes its
+    new rows into the stacked pools and reads its pages through a view of
+    them, so nothing slices a layer's pool out or stacks it back: with the
+    pools donated, the step updates them in place."""
     x = embed_tokens(params, tokens, cfg, ctx)
 
-    def body(x, layer_in):
-        lp, layer_pools = layer_in
-        y, new, aux = block_apply_paged(
+    def body(carry, layer_in):
+        x, stack_pools = carry
+        lp, layer = layer_in
+        y, stack_pools, aux = block_apply_paged(
             lp,
             x,
             cfg,
             ctx,
-            pools=layer_pools,
+            pools=stack_pools,
+            layer=layer,
             block_tables=block_tables,
             positions=positions,
             impl=impl,
             interpret=interpret,
         )
-        return y, (new, _counts(aux))
+        return (y, stack_pools), _counts(aux)
 
     new_pools, per_stack = {}, []
-    for name, _, _ in layer_stacks(cfg):
+    for name, _, n in layer_stacks(cfg):
         pre = _cache_prefix(name)
         mine = {leaf: pools[pre + leaf] for leaf in cache_leaves(cfg)}
-        x, (new, aux) = jax.lax.scan(body, x, (params[name], mine), unroll=cfg.scan_unroll)
-        new_pools.update({pre + k: v for k, v in new.items()})
+        (x, mine), aux = jax.lax.scan(
+            body, (x, mine), (params[name], jnp.arange(n, dtype=jnp.int32)),
+            unroll=cfg.scan_unroll,
+        )
+        new_pools.update({pre + k: v for k, v in mine.items()})
         per_stack.append(aux)
     logits = unembed(params, x, cfg, ctx)
     return logits, new_pools, _routed(per_stack)

@@ -6,6 +6,8 @@ contract (a request served through pages equals legacy `generate()`) rests
 on it, and test_scheduler.py builds on the model-level version checked here.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -280,6 +282,45 @@ def test_lm_decode_paged_bitwise_matches_lm_decode(arch):
         assert bool(jnp.all(lg_p[1, -1] == lg_d[1, -1])), f"step {i} diverged"
         tok = jnp.argmax(lg_d[1:2, -1, :], axis=-1).astype(jnp.int32)
         tok_p = jnp.argmax(lg_p[1:2, -1, :], axis=-1).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("impl", ["xla_gather", "pallas_paged"])
+@pytest.mark.parametrize("arch", ["deepseek-v2-lite", "olmoe-1b-7b"])
+def test_paged_decode_writes_each_row_into_its_own_layer(arch, impl):
+    """Two layer stacks (one leading dense layer, then two MoE layers) over
+    the latent pool (DeepSeek) and the K/V pools: one step through the
+    stacked-pool addressing changes exactly each live slot's row [layer,
+    page, :, off] of every layer, and row 0 of each layer's scratch page 0,
+    where the idle slots write.  Every other row of every page and layer
+    is bitwise unchanged."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), num_layers=3, first_k_dense=1)
+    model = get_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    ps, s_slots = 4, 4
+    specs = model.paged_pool_specs(9, ps)
+    assert sorted(specs) == sorted(
+        [k for leaf in ({"latent"} if cfg.is_mla else {"k", "v"}) for k in (leaf, "dense_" + leaf)]
+    )
+    keys = jax.random.split(jax.random.PRNGKey(1), len(specs))
+    # Random contents, so that any row the step writes reads changed.
+    pools = {
+        name: jax.random.normal(key, sd.shape, jnp.float32).astype(sd.dtype)
+        for key, (name, sd) in zip(keys, sorted(specs.items()))
+    }
+    # Slots 0 and 2 idle; slot 1 at position 5 (its 2nd page, 5, offset
+    # 1), slot 3 at position 2 (its 1st page, 7, offset 2).
+    bt = jnp.asarray([[0, 0, 0], [2, 5, 0], [0, 0, 0], [7, 3, 0]], jnp.int32)
+    positions = jnp.asarray([0, 5, 0, 2], jnp.int32)
+    toks = jnp.asarray([[0], [11], [0], [42]], jnp.int32)
+    logits, new = model.paged_decode(
+        params, toks, pools, bt, positions, impl=impl, interpret=impl == "pallas_paged"
+    )
+    assert bool(jnp.all(jnp.isfinite(logits)))
+    for name, old in pools.items():
+        changed = np.any(np.asarray(new[name]) != np.asarray(old), axis=(2, 4))  # (L, P, ps)
+        want = np.zeros_like(changed)
+        want[:, [5, 7, 0], [1, 2, 0]] = True
+        assert (changed == want).all(), name
 
 
 def test_paged_decode_rejects_multi_token():

@@ -26,6 +26,7 @@ from repro.launch.scheduler import (
 )
 from repro.launch.serve import generate, serving_steps
 from repro.models import ShardCtx, get_model
+from repro.obs import metrics as obs_metrics
 from repro.resilience import faults, ledger
 
 
@@ -532,6 +533,51 @@ def test_decode_step_traced_once_across_occupancy(dense):
     ]
     server.run(reqs)
     assert server._decode._cache_size() == 1
+
+
+@pytest.mark.parametrize("impl", ["xla_gather", "pallas_paged"])
+def test_steps_consume_the_pools_in_place(dense, impl):
+    """The admission scatter and the decode step are handed the pools and
+    consume them: the buffers handed in read deleted after each call, the
+    server holds the ones returned, and `serve_pool_in_place_total` counts
+    one per call of each step.  Warm-up keeps the pools its decode returns."""
+    model, params = dense
+    scfg = ServeConfig(
+        max_slots=2, page_size=8, num_pages=9, max_pages_per_seq=2,
+        impl=impl, interpret=impl == "pallas_paged",
+    )
+    server = ContinuousBatchingServer(model, params, scfg)
+    in_place = obs_metrics.counter("serve_pool_in_place_total", labels=("step",))
+    base = {step: in_place.value(step=step) for step in ("scatter", "decode")}
+
+    def counted():
+        return {step: in_place.value(step=step) - base[step] for step in base}
+
+    def deleted(pools):
+        return [p.is_deleted() for p in pools.values()]
+
+    first = server.pools
+    server.warmup()
+    assert all(deleted(first)) and not any(deleted(server.pools))
+    assert counted() == {"scatter": 0, "decode": 0}
+
+    server.submit(Request(rid="a", prompt=_prompt(0), max_new_tokens=3))
+    held = server.pools
+    server._admit()
+    assert all(deleted(held)) and not any(deleted(server.pools))
+    assert counted() == {"scatter": 1, "decode": 0}
+
+    server._ensure_pages()
+    held = server.pools
+    server._decode_tick()
+    assert all(deleted(held)) and not any(deleted(server.pools))
+    assert counted() == {"scatter": 1, "decode": 1}
+
+    held = server.pools
+    server.step()  # a tick that decodes and admits nothing
+    assert all(deleted(held))
+    assert counted() == {"scatter": 1, "decode": 2}
+    assert server.results["a"].tokens == _legacy_tokens(model, params, _prompt(0), 3)
 
 
 def test_generate_trace_count_flat_across_requests(dense):

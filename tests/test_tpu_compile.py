@@ -7,13 +7,17 @@ here lowers one kernel at mesh-paper widths (d_model 2048, 16 heads of 128,
 d_ff 8192) for one chip of a described `v5e:2x2` topology and checks that
 the compiled program holds the Mosaic kernel (`tpu_custom_call`).  Nothing
 runs; the kernels are called with `interpret=False` directly because the
-planner, asked here, sees the CPU.
+planner, asked here, sees the CPU.  The scheduler's whole decode step is
+compiled too, for the described chip and for the CPU, to see that it
+updates the paged pools in place.
 
 The topology is described inside a fixture, never at import: only one
 process may hold the TPU library, and every test worker imports this file.
 """
 
+import dataclasses
 import functools
+import re
 import sys
 from pathlib import Path
 
@@ -31,6 +35,9 @@ from repro.kernels.grouped import grouped_mesh_matmul_pallas  # noqa: E402
 from repro.kernels.mesh_matmul import mesh_matmul_pallas, mesh_matmul_pallas_batched  # noqa: E402
 from repro.kernels.paged_attention import paged_attention_pallas  # noqa: E402
 from repro.kernels.scramble_kernel import scramble_blocks_pallas  # noqa: E402
+from repro.configs import get_config  # noqa: E402
+from repro.launch.scheduler import ContinuousBatchingServer, ServeConfig  # noqa: E402
+from repro.models import get_model  # noqa: E402
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -216,3 +223,65 @@ def test_kernel_keeps_the_name_the_trace_readers_match(one_chip, family):
         if "tpu_custom_call" in line
     }
     assert family in kernels, kernels
+
+
+def _pool_rewrites(hlo: str, pool_shape) -> list:
+    """(op, dims) of every copy, transpose, slice or update in an HLO module
+    whose result is a whole stacked pool or one layer of it."""
+    rest = tuple(pool_shape[1:])
+    shapes = {tuple(pool_shape), (1,) + rest, rest}
+    found = re.findall(
+        r"= \w+\[([\d,]*)\]\S* (copy|transpose|dynamic-slice|dynamic-update-slice)\(", hlo
+    )
+    return [
+        (op, dims) for dims, op in found
+        if tuple(int(d) for d in dims.split(",") if d) in shapes
+    ]
+
+
+@pytest.mark.parametrize("chip, arch", [
+    ("cpu", "mesh-paper"),  # reduced: 2 layers, f32, xla_gather
+    ("v5e", "granite-3-8b"),  # published widths, 3 layers, pallas_paged
+    ("v5e", "deepseek-v2-lite"),  # the latent pools of a dense and an MoE stack
+])
+def test_decode_step_keeps_the_pools_in_place(request, monkeypatch, chip, arch):
+    """The scheduler's compiled decode step aliases every pool from its
+    argument to its result, and no op copies, transposes, slices out or
+    stacks back a whole pool or one layer of it: only the rows written and
+    the pages read touch the pools.  The TPU lays out what XLA:CPU does
+    not (a row written by a scatter or a dynamic-update-slice turned the
+    pool around it on the chip), so the chip's compile is checked too."""
+    if chip == "cpu":
+        cfg, sharding = get_config(arch).reduced(), None
+        scfg = ServeConfig(max_slots=2, page_size=8, num_pages=9, max_pages_per_seq=2)
+    else:
+        sharding = request.getfixturevalue("one_chip")
+        from repro.kernels import paged_attention as pa
+
+        # The door asks the CPU; the compile is for the described chip.
+        monkeypatch.setattr(pa, "_unavailable_reason", lambda impl, interpret: None)
+        cfg = dataclasses.replace(get_config(arch), num_layers=3)
+        scfg = ServeConfig(max_slots=8, page_size=16, num_pages=33, max_pages_per_seq=4,
+                           impl="pallas_paged")
+    model = get_model(cfg)
+    params = model.abstract_params()
+    server = ContinuousBatchingServer(model, params, scfg)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    s, n = scfg.max_slots, scfg.max_pages_per_seq
+    args = (
+        jax.tree.map(lambda p: spec(p.shape, p.dtype), params),
+        spec((s, 1), I32),
+        {name: spec(p.shape, p.dtype) for name, p in server.pools.items()},
+        spec((s, n), I32),
+        spec((s,), I32),
+    )
+    hlo = server._decode.lower(*args).compile().as_text()
+    header = hlo.splitlines()[0]
+    assert header.count("may-alias") + header.count("must-alias") == len(server.pools)
+    for pool in server.pools.values():
+        assert _pool_rewrites(hlo, pool.shape) == []
+    if sharding:
+        _assert_kernel(hlo)
